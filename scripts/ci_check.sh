@@ -10,13 +10,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-if ! python -c "import hypothesis" 2>/dev/null; then
-  # try to heal the env first: when network allows, real hypothesis
-  # replaces the propshim and the property tests get shrinking + fresh
-  # examples. Offline (the common container case) this fails quietly and
-  # the fallback notice below stands.
-  pip install -q -r requirements-dev.txt 2>/dev/null || true
-fi
 # one unambiguous machine-greppable line naming the property-test engine
 if python -c "import hypothesis" 2>/dev/null; then
   echo "property-engine: hypothesis $(python -c 'import hypothesis; print(hypothesis.__version__)') (full shrinking; pin: requirements-dev.txt)"
@@ -55,7 +48,7 @@ echo "== serve smoke: paged KV engine, 3 staggered requests =="
 python -m repro.launch.serve --arch llama_60m --smoke --paged --block-len 8 \
   --requests 3 --stagger --slots 2 --new-tokens 4 --max-len 64
 
-echo "== serve smoke: paged-attention kernel decode (interpret mode) =="
+echo "== serve smoke: paged-attention kernel decode =="
 python -m repro.launch.serve --arch llama_60m --smoke --paged \
   --attn-kernel paged --block-len 8 --requests 3 --stagger --slots 2 \
   --new-tokens 4 --max-len 64

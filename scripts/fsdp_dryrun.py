@@ -30,7 +30,6 @@ from jax.sharding import NamedSharding
 
 from repro.configs.base import OptimizerConfig, ShapeCell
 from repro.core import memory as memory_lib
-from repro.dist import compat
 from repro.dist import sharding as shl
 from repro.models import registry
 from repro.optim import optimizers
@@ -65,9 +64,9 @@ def main(argv=None):
     assert jax.device_count() >= N_DEV, (
         f"need >= {N_DEV} host devices, got {jax.device_count()} — is "
         "another jax init clobbering xla_force_host_platform_device_count?")
-    mesh = compat.make_mesh(
+    mesh = jax.make_mesh(
         (N_DEV, 1), ("data", "model"),
-        axis_types=(compat.AxisType.Auto,) * 2)
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     cfg = registry.get_config(ARCH)
     api = registry.get_api(cfg)

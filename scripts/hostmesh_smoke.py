@@ -41,7 +41,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis import roofline as roofline_lib
 from repro.configs.base import OptimizerConfig
 from repro.data.pipeline import SyntheticC4
-from repro.dist import compat, compression
+from repro.dist import compression
 from repro.models import registry
 from repro.obs import metrics as obs_metrics
 from repro.optim import optimizers
@@ -71,8 +71,8 @@ def _batches(cfg, n, batch=4, seq=32):
 
 
 def _pod_mesh():
-    return compat.make_mesh((2,), ("pod",),
-                            axis_types=(compat.AxisType.Auto,))
+    return jax.make_mesh((2,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def smoke_wire_model():
@@ -129,8 +129,8 @@ def smoke_compressed_dp():
 def smoke_perlayer_grad_accum():
     """per_layer + grad_accum=2 == global + grad_accum=2 on a data-sharded
     2-device mesh, 3 steps token for token."""
-    mesh = compat.make_mesh((2, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = _smoke_cfg()
     api, params, consts, opt, opt_state = _state(cfg)
     g_step = jax.jit(step_lib.make_train_step(cfg, api, opt, grad_accum=2))
@@ -162,8 +162,8 @@ def smoke_fused_dist():
     from repro.core import sltrain
     from repro.kernels import ops
 
-    mesh = compat.make_mesh((1, 2), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((1, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     d_in, d_out, r, delta, scale = 256, 256, 16, 0.05, 0.5
     params, consts = sltrain.init_params(
         jax.random.PRNGKey(3), d_in, d_out, r, delta, jnp.float32,

@@ -10,8 +10,8 @@ and sum operand/result sizes of every collective op with ring-algorithm
 factors:  all-reduce 2(n−1)/n · S,  all-gather/reduce-scatter (n−1)/n · S,
 all-to-all (n−1)/n · S,  collective-permute 1 · S   (per participant).
 
-Hardware model (TPU v5e-like, from the assignment): 197 TFLOP/s bf16,
-819 GB/s HBM, 50 GB/s/link ICI.
+Hardware model: the published per-chip peaks in :data:`PEAKS`, keyed by
+jax's ``device_kind``. The dry-run models a TPU v5e fleet (:data:`V5E`).
 """
 from __future__ import annotations
 
@@ -20,12 +20,38 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 # ---------------------------------------------------------------------------
-# Hardware constants (assignment-provided)
+# Published per-chip peaks, keyed by device_kind
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
+@dataclass(frozen=True)
+class DevicePeaks:
+    flops: float               # dense bf16 FLOP/s per chip
+    hbm_bw: float              # HBM bytes/s per chip
+    link_bw: float             # ICI bytes/s per link
+    source: str
+
+
+V5E = "TPU v5 lite"
+PEAKS: Dict[str, DevicePeaks] = {
+    # 1,600 Gbit/s of ICI per chip, over 4 links
+    V5E: DevicePeaks(197e12, 819e9, 50e9,
+                     "Google Cloud documentation, 'TPU v5e'"),
+}
+
+
+def peaks_for(device) -> Optional[DevicePeaks]:
+    """Peaks of ``device`` (a ``jax.Device``): None on the CPU, where no
+    device metric is measured; an accelerator missing from :data:`PEAKS`
+    is an error, never a default."""
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for {device.platform} device kind "
+            f"{device.device_kind!r}: add it to analysis.roofline.PEAKS "
+            "with its source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "s32": 4, "u32": 4,
@@ -136,15 +162,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS)
+        return self.flops / (self.chips * PEAKS[V5E].flops)
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / (self.chips * HBM_BW)
+        return self.hbm_bytes / (self.chips * PEAKS[V5E].hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.wire_bytes / (self.chips * LINK_BW)
+        return self.wire_bytes / (self.chips * PEAKS[V5E].link_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -238,11 +264,12 @@ def model_flops(cfg, n_tokens: int, kind: str = "train") -> float:
     return mult * active * n_tokens
 
 
-def train_mfu(cfg, n_tokens: int, dt_s: float, chips: int = 1) -> float:
+def train_mfu(cfg, n_tokens: int, dt_s: float, peaks: DevicePeaks,
+              chips: int = 1) -> float:
     """Model FLOPs utilisation of one training step: the 6·N·D model
     FLOPs actually delivered per second, as a fraction of the chips' peak
-    (``PEAK_FLOPS`` each). The trainer publishes this per step as the
-    ``train.mfu`` gauge."""
+    (``peaks.flops`` each). The trainer publishes this per step as the
+    ``train.mfu`` gauge, on a device with published peaks only."""
     if dt_s <= 0:
         return 0.0
-    return model_flops(cfg, n_tokens, "train") / dt_s / (chips * PEAK_FLOPS)
+    return model_flops(cfg, n_tokens, "train") / dt_s / (chips * peaks.flops)

@@ -215,7 +215,7 @@ def _grads_distributed(x, dy, A, B, v, cols, scale):
     gather-W form (the it.6 lesson: a seq-sharded island de-sharded the
     whole backward region, 5× compute). Each device computes the
     (d_in × d_out/TP) G slice it would have computed as a partial anyway."""
-    from repro.dist import compat, sharding as dist_sharding
+    from repro.dist import sharding as dist_sharding
     mesh = dist_sharding.ambient_mesh()
     if mesh is None or getattr(mesh, "empty", False) or x.ndim < 3:
         return None
@@ -256,7 +256,7 @@ def _grads_distributed(x, dy, A, B, v, cols, scale):
         return dB, dA, dv
 
     try:
-        dB, dA, dv = compat.shard_map(
+        dB, dA, dv = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(bt, None, None), P(bt, None, "model"),
                       P(None, "model"), P(None, None), P(None, None)),

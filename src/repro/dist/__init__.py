@@ -2,10 +2,6 @@
 
 Modules:
 
-* :mod:`repro.dist.compat` — version-portable ``shard_map`` /
-  ``make_mesh`` / ``AxisType`` wrappers; grafts the modern jax names onto
-  old pins (``install_forward_compat``, run on package import so every
-  ``import repro.dist`` makes modern-style call sites work).
 * :mod:`repro.dist.sharding` — the PartitionSpec engine (param / batch /
   opt-state / cache specs from pytree paths), mesh construction, and the
   ambient-mesh ``constrain`` helper model code uses.
@@ -15,8 +11,4 @@ Modules:
 Everything above this package (models, train, launch, serve, scripts)
 talks to meshes, specs, and collectives only through these modules.
 """
-from repro.dist import compat
-
-compat.install_forward_compat()
-
-from repro.dist import compression, sharding  # noqa: E402,F401
+from repro.dist import compression, sharding  # noqa: F401

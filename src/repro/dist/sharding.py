@@ -3,8 +3,7 @@
 Single owner of every mesh/sharding decision in the repo:
 
 * **Mesh construction** — :func:`make_production_mesh` (16×16 single-pod,
-  2×16×16 multi-pod) and :func:`make_local_mesh`, built through the
-  version-portable :mod:`repro.dist.compat` layer.
+  2×16×16 multi-pod) and :func:`make_local_mesh` (every visible device).
 * **Ambient-mesh probing** — :func:`ambient_mesh` / :func:`constrain`, the
   degrading ``with_sharding_constraint`` used inside model code (moved
   here from ``models/common.py`` so model files carry no mesh logic).
@@ -53,7 +52,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
 
 MODEL_AXIS = "model"
 BATCH_AXES = ("pod", "data")
@@ -67,14 +65,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes,
-                            axis_types=(compat.AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
-    """Single-device mesh with the same axis names (tests / CPU training)."""
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=(compat.AxisType.Auto,) * 2)
+    """Every visible device on the data axis (model = 1), with the
+    production axis names: one chip, one host of chips, or the CPU."""
+    return jax.make_mesh((jax.device_count(), 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 # ---------------------------------------------------------------------------
@@ -387,9 +386,9 @@ def cache_specs(cache, mesh, batch_axes: Sequence[str] = BATCH_AXES,
 
     * ``"gather"`` — the gathered per-slot view inherits the head
       sharding (XLA places the gather per shard);
-    * ``"paged"`` — kernels/paged_attention.py grids over the kv-head
-      dim, so the SAME head sharding makes each device stream only its
-      local heads' blocks; whole GQA q-head groups land with their kv
+    * ``"paged"`` — kernels/paged_attention.py fetches a page's kv
+      heads together, so the SAME head sharding makes each device stream
+      only its local heads' blocks; whole GQA q-head groups land with their kv
       head automatically because the wq output sharding divides by the
       identical model-axis factor. The kernel cannot split the sequence
       (block) dims across devices, so ``seq_sharded=True`` is rejected
@@ -405,7 +404,7 @@ def cache_specs(cache, mesh, batch_axes: Sequence[str] = BATCH_AXES,
     if paged and attn_kernel == "paged" and seq_sharded:
         raise ValueError(
             "attn_kernel='paged' cannot run seq-sharded: the kernel "
-            "streams whole K/V blocks per (slot, head) grid cell, so the "
+            "streams whole K/V blocks per (slot, block) grid cell, so the "
             "sequence/block dims must stay replicated — use the head-"
             "sharded TP layout (default) or attn_kernel='gather'")
     axes = tuple(a for a in batch_axes if a in mesh.axis_names)

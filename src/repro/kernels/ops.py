@@ -2,8 +2,11 @@
 support preparation, and the custom-VJP SLTrain linear that fuses
 ``sl_matmul`` forward with the ``sddmm`` backward.
 
-``interpret=True`` everywhere on CPU (this container); on TPU the same
-calls lower to real Mosaic kernels (interpret=False).
+Every wrapper decides the execution mode from the platform once
+(:func:`interpret_mode`): the Pallas interpreter on the CPU, where the
+tests run, and compiled Mosaic kernels on a TPU. Any other backend raises.
+An explicit ``interpret`` argument exists only so a test can compile the
+real kernels for a described TPU from a CPU process.
 """
 from __future__ import annotations
 
@@ -19,7 +22,19 @@ from repro.kernels import adam8bit as adam8bit_kernel
 from repro.kernels import sddmm as sddmm_kernel
 from repro.kernels import sl_matmul as sl_kernel
 
-INTERPRET = True  # flipped to False by the TPU launcher
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """``interpret`` if given, else the platform's mode: True on the CPU,
+    False on a TPU. No other backend runs these kernels."""
+    if interpret is not None:
+        return interpret
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run on 'cpu' (interpreted) or "
+                       f"'tpu' (compiled), not on {platform!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +115,7 @@ def _pad2(x, mult_r, mult_c):
 def sl_matmul(x, B, A, v_t, rows_t, cols_t, scale: float, *,
               bm: int = 128, interpret: bool | None = None):
     """y = x @ (scale·B·A ⊕ V); arbitrary (unpadded) logical shapes."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = interpret_mode(interpret)
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = A.shape[-1]
@@ -123,7 +138,7 @@ def sddmm(x, dy, rows_t, cols_t, *, bm: int = 128,
     accumulation contract as the sparse-decode fix). Upstream often hands
     f32 cotangents against bf16 activations — align dy to x's dtype here
     (the MXU dot needs matching operand dtypes; accumulation stays f32)."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = interpret_mode(interpret)
     k = x.shape[-1]
     n = dy.shape[-1]
     xf = _pad2(x.reshape(-1, k), bm, 128)
@@ -135,6 +150,13 @@ def sddmm(x, dy, rows_t, cols_t, *, bm: int = 128,
 # ---------------------------------------------------------------------------
 # Fused SLTrain linear: pallas forward + pallas backward, custom VJP
 # ---------------------------------------------------------------------------
+
+def _tok_dot(a, b):
+    """aᵀ·b contracting the leading (token) axis in place. Spelled as one
+    dot_general rather than ``a.T @ b``: XLA's CPU backend folds the
+    transpose of a bf16·bf16→f32 product into a dot form it cannot run."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())))
+
 
 def _fused_grads_dist(x, B, A, v_t, rows_t, cols_t, scale, dy):
     """Distributed fused backward (the shard_map sibling of
@@ -162,7 +184,7 @@ def _fused_grads_dist(x, B, A, v_t, rows_t, cols_t, scale, dy):
     island: composition must degrade, never error."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import compat, sharding as dist_sharding
+    from repro.dist import sharding as dist_sharding
     mesh = dist_sharding.ambient_mesh()
     if mesh is None or getattr(mesh, "empty", False) or x.ndim != 3 \
             or dy.ndim != 3:
@@ -189,10 +211,10 @@ def _fused_grads_dist(x, B, A, v_t, rows_t, cols_t, scale, dy):
         dyl = dys.reshape(-1, d_out_loc).astype(xl.dtype)
         xB = jnp.matmul(xl, B_r, preferred_element_type=f32)
         dA = jax.lax.psum(
-            scale * jnp.matmul(xB.T, dyl.astype(f32)), bt)
+            scale * _tok_dot(xB, dyl.astype(f32)), bt)
         dyA = jnp.matmul(dyl, A_l.T, preferred_element_type=f32)
         dB = jax.lax.psum(
-            scale * jnp.matmul(xl.astype(f32).T, dyA), bt + ("model",))
+            scale * _tok_dot(xl.astype(f32), dyA), bt + ("model",))
         dv = jax.lax.psum(sddmm(xl, dyl, rt_l, ct_l), bt)
         dx = sl_matmul(dyl, A_l.T, B_r.T, jnp.swapaxes(vt_l, 0, 1),
                        jnp.swapaxes(ct_l, 0, 1), jnp.swapaxes(rt_l, 0, 1),
@@ -201,7 +223,7 @@ def _fused_grads_dist(x, B, A, v_t, rows_t, cols_t, scale, dy):
         return dx, dB, dA, dv
 
     try:
-        dx, dB, dA, dv_t = compat.shard_map(
+        dx, dB, dA, dv_t = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(bt, None, None), P(bt, None, "model"),
                       P(None, None), P(None, "model"),
@@ -242,9 +264,9 @@ def _fused_grads(x, B, A, v_t, rows_t, cols_t, scale, dy):
     # MXU speed; the second-level dots carry the f32 intermediate
     f32 = jnp.float32
     xB = jnp.matmul(xf, B, preferred_element_type=f32)    # (M, r) f32
-    dA = (scale * jnp.matmul(xB.T, dyf.astype(f32))).astype(A.dtype)
+    dA = (scale * _tok_dot(xB, dyf.astype(f32))).astype(A.dtype)
     dyA = jnp.matmul(dyf, A.T, preferred_element_type=f32)  # (M, r) f32
-    dB = (scale * jnp.matmul(xf.astype(f32).T, dyA)).astype(B.dtype)
+    dB = (scale * _tok_dot(xf.astype(f32), dyA)).astype(B.dtype)
     dv_t = sddmm(xf, dyf, rows_t, cols_t)                 # f32 tiles
     # dx = dy @ W^T: reuse the fused kernel on the transposed factors. The
     # support transpose is (cols_t, rows_t) tiles transposed in the grid —
@@ -338,37 +360,37 @@ def adam8bit_update(p, g, m_codes, m_scales, v_codes, v_scales, *,
     matching the ``optim/quant.py`` reference bitwise (an in-kernel f32
     ``1 - b2`` loses ~half the bits of the ~1e-3 difference — ISSUE-4
     audit)."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = interpret_mode(interpret)
     shape = p.shape
     n = p.size
-    pad = (-n) % q
     if omb1 is None:
         omb1 = 1.0 - b1
     if omb2 is None:
         omb2 = 1.0 - b2
 
-    def blk(a):
-        """Pad a logical-size leaf (p, g) up to whole quantization blocks.
-        Codes/scales are already block-shaped and pass through reshape."""
-        f = a.reshape(-1)
-        if f.size == n and pad:
-            f = jnp.pad(f, (0, pad))
-        return f.reshape(-1, q)
+    n_q = -(-n // q)
+    # rows per kernel instance: a multiple of 32, the int8 sublane tile; a
+    # leaf whose block count it does not divide gets zero blocks appended,
+    # which the n_valid mask keeps out of every result
+    bb = 64 if n_q % 64 == 0 else 32
+    n_rows = n_q + (-n_q) % bb
 
-    n_q = (n + pad) // q
-    bb = 1
-    for cand in (64, 32, 16, 8, 4, 2, 1):
-        if n_q % cand == 0:
-            bb = cand
-            break
+    def blk(a):
+        """Pad a leaf (p, g) or its codes to n_rows whole blocks."""
+        f = a.reshape(-1)
+        return jnp.pad(f, (0, n_rows * q - f.size)).reshape(n_rows, q)
+
+    def col(s):
+        return jnp.pad(s.reshape(-1), (0, n_rows - n_q)).reshape(n_rows, 1)
+
     scalars = jnp.array([lr, b1, b2, omb1, omb2, bc1, bc2, eps, wd, 0.0],
                         jnp.float32)
     n_valid = jnp.array([n], jnp.int32)
     new_p, mc, ms, vc, vs = adam8bit_kernel.adam8bit_update(
-        blk(p), blk(g), blk(m_codes), m_scales.reshape(-1),
-        blk(v_codes), v_scales.reshape(-1), scalars, n_valid,
-        bb=bb, interpret=interp)
-    return (new_p.reshape(-1)[:n].reshape(shape), mc, ms, vc, vs)
+        blk(p), blk(g), blk(m_codes), col(m_scales), blk(v_codes),
+        col(v_scales), scalars, n_valid, bb=bb, interpret=interp)
+    return (new_p.reshape(-1)[:n].reshape(shape), mc[:n_q], ms[:n_q, 0],
+            vc[:n_q], vs[:n_q, 0])
 
 
 # ---------------------------------------------------------------------------
@@ -391,7 +413,7 @@ def paged_attention(q, k_pool, v_pool, block_table, positions, *,
     blocks, not n_slots × view_len.
     """
     from repro.kernels import paged_attention as pa_kernel
-    interp = INTERPRET if interpret is None else interpret
+    interp = interpret_mode(interpret)
     n_slots, n_heads, hd = q.shape
     n_kv = k_pool.shape[2]
     assert n_heads % n_kv == 0, (n_heads, n_kv)
@@ -419,7 +441,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table, offsets, *,
     hd) in q.dtype; padding rows / idle slots come back as exact zeros.
     """
     from repro.kernels import paged_attention as pa_kernel
-    interp = INTERPRET if interpret is None else interpret
+    interp = interpret_mode(interpret)
     n_slots, sq, n_heads, hd = q.shape
     n_kv = k_pool.shape[2]
     assert n_heads % n_kv == 0, (n_heads, n_kv)
@@ -441,13 +463,13 @@ def sl_decode(x, B, A, v_t, rows_t, cols_t, scale: float, *,
     sparse_decode kernel (DESIGN §3 beyond-paper). Reads factored bytes
     only — the decode HBM term drops by the compression ratio."""
     from repro.kernels import sparse_decode as sd_kernel
-    interp = INTERPRET if interpret is None else interpret
+    interp = interpret_mode(interpret)
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = A.shape[-1]
     xf = x.reshape(-1, k)
     m = xf.shape[0]
-    bm = 8
+    bm = 16
     pad_m = (-m) % bm
     pad_k = (-k) % 128
     xp = jnp.pad(xf, ((0, pad_m), (0, pad_k)))
@@ -468,13 +490,13 @@ def sl_quant_decode(x, B, A, qv_t, rows_q, cols_q, qscale, scale: float, *,
     term reads qv int8 + int16 local indices + the per-channel f32 scale
     vector — ~5·δ B/cell vs the bf16 tile-CSR's 12·δ."""
     from repro.kernels import sparse_decode as sd_kernel
-    interp = INTERPRET if interpret is None else interpret
+    interp = interpret_mode(interpret)
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = A.shape[-1]
     xf = x.reshape(-1, k)
     m = xf.shape[0]
-    bm = 8
+    bm = 16
     xp = jnp.pad(xf, ((0, (-m) % bm), (0, (-k) % 128)))
     y_lr = ((xf.astype(jnp.float32) @ B.astype(jnp.float32))
             @ A.astype(jnp.float32)) * scale

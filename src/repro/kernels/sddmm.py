@@ -9,7 +9,7 @@ HBM.
 Gather-as-matmul: dv[e] = G[row_e, col_e] = (P_r · G ⊙ P_c)·1, i.e. one
 (E, bk)@(bk, bn) MXU matmul + a masked row-sum, where P_r/P_c are the
 one-hot support matrices of the tile. Grid: (K/bk, N/bn, M/bm), m
-innermost, accumulating into the (1, 1, E) output block.
+innermost, accumulating into the tile's (1, E) output block.
 """
 from __future__ import annotations
 
@@ -18,6 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.sl_matmul import as_tile_rows, tile_block
 
 
 def _kernel(x_ref, dy_ref, r_ref, c_ref, o_ref):
@@ -29,23 +31,26 @@ def _kernel(x_ref, dy_ref, r_ref, c_ref, o_ref):
 
     bk = x_ref.shape[1]
     bn = dy_ref.shape[1]
-    # tile of G = x^T dy, f32 on the MXU
-    g = jax.lax.dot(x_ref[...].T, dy_ref[...],
-                    preferred_element_type=jnp.float32)      # (bk, bn)
-    rows = r_ref[0, 0, :]
-    cols = c_ref[0, 0, :]
-    e = rows.shape[0]
-    pr = (rows[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bk), 1))
-    pc = (cols[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bn), 1))
-    rows_of_g = jax.lax.dot(pr.astype(jnp.float32), g,
-                            preferred_element_type=jnp.float32)  # (E, bn)
-    dv = jnp.sum(rows_of_g * pc.astype(jnp.float32), axis=1)     # (E,)
-    o_ref[...] += dv[None, None, :]
+    e = r_ref.shape[-1]
+    # transposed tile of G = xᵀ·dy: (bn, bk), f32 on the MXU
+    g_t = jax.lax.dot_general(dy_ref[...], x_ref[...],
+                              (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    # one-hots built transposed, (bk, E) and (bn, E), from the lane-major
+    # (1, E) support vectors: column e of Gᵀ·P_rᵀ is row rows[e] of G, and
+    # the P_cᵀ mask keeps its cols[e] entry
+    prt = (jax.lax.broadcasted_iota(jnp.int32, (bk, e), 0) == r_ref[...]
+           ).astype(jnp.float32)
+    pct = (jax.lax.broadcasted_iota(jnp.int32, (bn, e), 0) == c_ref[...]
+           ).astype(jnp.float32)
+    rows_of_g = jax.lax.dot(g_t, prt,
+                            preferred_element_type=jnp.float32)  # (bn, E)
+    o_ref[...] += jnp.sum(rows_of_g * pct, axis=0, keepdims=True)  # (1, E)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def sddmm(x, dy, rows_t, cols_t, *, bm: int = 128, bk: int = 128,
-          bn: int = 128, interpret: bool = True):
+          bn: int = 128, interpret: bool):
     """dv tiles (K/bk, N/bn, E) f32 for the support laid out by
     ``ops.prepare_tiles``; x (M, K), dy (M, N) pre-padded to tile multiples."""
     m, k = x.shape
@@ -53,17 +58,18 @@ def sddmm(x, dy, rows_t, cols_t, *, bm: int = 128, bk: int = 128,
     assert m % bm == 0 and k % bk == 0 and n % bn == 0, (m, k, n)
     nkt, nnt, e = rows_t.shape
     assert (nkt, nnt) == (k // bk, n // bn), rows_t.shape
+    tile = lambda kk, j, i: (kk, j, 0, 0)
     grid = (k // bk, n // bn, m // bm)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda kk, j, i: (i, kk)),
             pl.BlockSpec((bm, bn), lambda kk, j, i: (i, j)),
-            pl.BlockSpec((1, 1, e), lambda kk, j, i: (kk, j, 0)),
-            pl.BlockSpec((1, 1, e), lambda kk, j, i: (kk, j, 0)),
+            tile_block(e, tile), tile_block(e, tile),
         ],
-        out_specs=pl.BlockSpec((1, 1, e), lambda kk, j, i: (kk, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nkt, nnt, e), jnp.float32),
+        out_specs=tile_block(e, tile),
+        out_shape=jax.ShapeDtypeStruct((nkt, nnt, 1, e), jnp.float32),
         interpret=interpret,
-    )(x, dy, rows_t, cols_t)
+    )(x, dy, as_tile_rows(rows_t), as_tile_rows(cols_t))
+    return out.reshape(nkt, nnt, e)

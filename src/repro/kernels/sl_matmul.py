@@ -30,8 +30,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def sparse_tile(v, rows, cols, bk: int, bn: int):
+    """(bk, bn) f32 tile P_rᵀ·diag(v)·P_c from one tile's (1, E) support
+    row vectors (local rows, local cols, values). The one-hots are built
+    transposed, (bk, E) and (bn, E), so the lane-major support vectors
+    broadcast along sublanes and the product is one A·Bᵀ MXU matmul."""
+    e = rows.shape[-1]
+    prt = (jax.lax.broadcasted_iota(jnp.int32, (bk, e), 0) == rows
+           ).astype(jnp.float32) * v.astype(jnp.float32)
+    pct = (jax.lax.broadcasted_iota(jnp.int32, (bn, e), 0) == cols
+           ).astype(jnp.float32)
+    return jax.lax.dot_general(prt, pct, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def tile_block(e: int, index_map):
+    """BlockSpec of one tile's support vector in the (nkt, nnt, 1, E)
+    layout (see :func:`as_tile_rows`): the last two block dims equal the
+    array's, which the TPU tiling requires; the kernel sees a (1, E) ref."""
+    return pl.BlockSpec((None, None, 1, e), index_map)
+
+
+def as_tile_rows(a):
+    """(nkt, nnt, E) tile-CSR array → (nkt, nnt, 1, E), a free reshape."""
+    return a.reshape(*a.shape[:2], 1, a.shape[2])
+
+
 def _kernel(x_ref, b_ref, a_ref, v_ref, r_ref, c_ref, o_ref, *,
-            scale: float, n_k: int):
+            scale: float):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -44,15 +70,7 @@ def _kernel(x_ref, b_ref, a_ref, v_ref, r_ref, c_ref, o_ref, *,
     w = jax.lax.dot(b_ref[...], a_ref[...],
                     preferred_element_type=jnp.float32) * scale
     # sparse tile via one-hot matmuls (scatter-as-matmul)
-    rows = r_ref[0, 0, :]                                # (E,) local row ids
-    cols = c_ref[0, 0, :]
-    v = v_ref[0, 0, :].astype(jnp.float32)
-    e = rows.shape[0]
-    pr = (rows[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bk), 1))
-    pc = (cols[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bn), 1))
-    pr_v = pr.astype(jnp.float32) * v[:, None]           # diag(v) folded in
-    w = w + jax.lax.dot(pr_v.T, pc.astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
+    w = w + sparse_tile(v_ref[...], r_ref[...], c_ref[...], bk, bn)
     # consume the tile immediately: (bm, bk) @ (bk, bn)
     o_ref[...] += jax.lax.dot(x_ref[...], w.astype(x_ref.dtype),
                               preferred_element_type=jnp.float32)
@@ -62,7 +80,7 @@ def _kernel(x_ref, b_ref, a_ref, v_ref, r_ref, c_ref, o_ref, *,
                                              "interpret"))
 def sl_matmul(x, B, A, v_t, rows_t, cols_t, *, scale: float,
               bm: int = 128, bk: int = 128, bn: int = 128,
-              interpret: bool = True):
+              interpret: bool):
     """x (M,K) @ (scale·B(K,r)·A(r,N) ⊕ V) → (M,N) in x.dtype.
 
     v_t/rows_t/cols_t: (K/bk, N/bn, E) tile-CSR arrays from
@@ -73,20 +91,21 @@ def sl_matmul(x, B, A, v_t, rows_t, cols_t, *, scale: float,
     n = A.shape[1]
     assert m % bm == 0 and k % bk == 0 and n % bn == 0, (m, k, n)
     assert rows_t.shape[:2] == (k // bk, n // bn), rows_t.shape
+    e = v_t.shape[-1]
+    tile = lambda i, j, kk: (kk, j, 0, 0)
     grid = (m // bm, n // bn, k // bk)
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, n_k=grid[2]),
+        functools.partial(_kernel, scale=scale),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, B.shape[1]), lambda i, j, kk: (kk, 0)),
             pl.BlockSpec((A.shape[0], bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec((1, 1, v_t.shape[-1]), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, rows_t.shape[-1]), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, cols_t.shape[-1]), lambda i, j, kk: (kk, j, 0)),
+            tile_block(e, tile), tile_block(e, tile), tile_block(e, tile),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, B, A, v_t, rows_t, cols_t)
+    )(x, B, A, as_tile_rows(v_t), as_tile_rows(rows_t),
+      as_tile_rows(cols_t))
     return out.astype(x.dtype)

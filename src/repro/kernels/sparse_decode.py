@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.sl_matmul import as_tile_rows, sparse_tile, tile_block
+
 
 def _kernel(x_ref, v_ref, r_ref, c_ref, o_ref):
     k = pl.program_id(2)
@@ -40,42 +42,34 @@ def _kernel(x_ref, v_ref, r_ref, c_ref, o_ref):
 
     bk = x_ref.shape[1]
     bn = o_ref.shape[1]
-    rows = r_ref[0, 0, :]
-    cols = c_ref[0, 0, :]
-    v = v_ref[0, 0, :].astype(jnp.float32)
-    e = rows.shape[0]
-    pr = (rows[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bk), 1))
-    pc = (cols[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bn), 1))
-    s_tile = jax.lax.dot((pr.astype(jnp.float32) * v[:, None]).T,
-                         pc.astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
+    s_tile = sparse_tile(v_ref[...], r_ref[...], c_ref[...], bk, bn)
     o_ref[...] += jax.lax.dot(x_ref[...].astype(jnp.float32), s_tile,
                               preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
-def sparse_matmul(x, v_t, rows_t, cols_t, *, bm: int = 8, bk: int = 128,
-                  bn: int = 128, interpret: bool = True):
+def sparse_matmul(x, v_t, rows_t, cols_t, *, bm: int = 16, bk: int = 128,
+                  bn: int = 128, interpret: bool):
     """y = x @ S for tile-CSR S; x (M, K) pre-padded to tile multiples.
-    bm defaults small — decode batches are 1–128 rows."""
+    bm defaults small — decode batches are 1–128 rows; 16 is the bf16
+    sublane tile."""
     m, k = x.shape
     nkt, nnt, e = rows_t.shape
     n = nnt * bn
     assert m % bm == 0 and k % bk == 0, (m, k)
+    tile = lambda i, j, kk: (kk, j, 0, 0)
     grid = (m // bm, nnt, nkt)
     out = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((1, 1, e), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, e), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, e), lambda i, j, kk: (kk, j, 0)),
+            tile_block(e, tile), tile_block(e, tile), tile_block(e, tile),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, v_t, rows_t, cols_t)
+    )(x, as_tile_rows(v_t), as_tile_rows(rows_t), as_tile_rows(cols_t))
     return out.astype(x.dtype)
 
 
@@ -88,27 +82,20 @@ def _qkernel(x_ref, qv_ref, r_ref, c_ref, s_ref, o_ref):
 
     bk = x_ref.shape[1]
     bn = o_ref.shape[1]
-    rows = r_ref[0, 0, :].astype(jnp.int32)
-    cols = c_ref[0, 0, :].astype(jnp.int32)
-    qv = qv_ref[0, 0, :].astype(jnp.float32)
-    e = rows.shape[0]
-    pr = (rows[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bk), 1))
-    pc = (cols[:, None] == jax.lax.broadcasted_iota(jnp.int32, (e, bn), 1))
     # tile of raw int8 codes (padding slots carry qv == 0), then one
     # row-vector multiply dequantizes every column against its channel
     # scale — column c of s_tile holds exactly the entries with col == c
-    s_tile = jax.lax.dot((pr.astype(jnp.float32) * qv[:, None]).T,
-                         pc.astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
-    s_tile = s_tile * s_ref[0, :][None, :]
+    s_tile = sparse_tile(qv_ref[...].astype(jnp.float32),
+                         r_ref[...].astype(jnp.int32),
+                         c_ref[...].astype(jnp.int32), bk, bn)
+    s_tile = s_tile * s_ref[...]
     o_ref[...] += jax.lax.dot(x_ref[...].astype(jnp.float32), s_tile,
                               preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
-def quant_sparse_matmul(x, qv_t, rows_q, cols_q, qscale, *, bm: int = 8,
-                        bk: int = 128, bn: int = 128,
-                        interpret: bool = True):
+def quant_sparse_matmul(x, qv_t, rows_q, cols_q, qscale, *, bm: int = 16,
+                        bk: int = 128, bn: int = 128, interpret: bool):
     """y = x @ dequant(S) for the int8 tile-CSR layout (repro.quant).
 
     qv_t int8 (nkt, nnt, E) codes baked in tile order; rows_q/cols_q
@@ -121,19 +108,19 @@ def quant_sparse_matmul(x, qv_t, rows_q, cols_q, qscale, *, bm: int = 8,
     n = nnt * bn
     assert m % bm == 0 and k % bk == 0, (m, k)
     assert qscale.shape == (nnt, bn), (qscale.shape, nnt, bn)
+    tile = lambda i, j, kk: (kk, j, 0, 0)
     grid = (m // bm, nnt, nkt)
     out = pl.pallas_call(
         _qkernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((1, 1, e), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, e), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, e), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (j, 0)),
+            tile_block(e, tile), tile_block(e, tile), tile_block(e, tile),
+            pl.BlockSpec((None, 1, bn), lambda i, j, kk: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, qv_t, rows_q, cols_q, qscale)
+    )(x, as_tile_rows(qv_t), as_tile_rows(rows_q), as_tile_rows(cols_q),
+      qscale.reshape(nnt, 1, bn))
     return out.astype(x.dtype)

@@ -1,8 +1,7 @@
 """Production mesh definitions — thin forwarder.
 
-Mesh construction is owned by :mod:`repro.dist.sharding` (built through
-the version-portable :mod:`repro.dist.compat` layer); this module keeps
-the historical ``repro.launch.mesh`` import path alive. Both are
+Mesh construction is owned by :mod:`repro.dist.sharding`; this module
+keeps the historical ``repro.launch.mesh`` import path alive. Both are
 FUNCTIONS, not module-level constants — importing never touches jax
 device state (required so smoke tests see 1 device while the dry-run
 sees 512)."""

@@ -21,6 +21,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.obs import trace as obs_trace
 from repro.serve.engine import ServeEngine
@@ -104,6 +105,7 @@ def main(argv=None):
                          "sparse path (warn + serve) when the artifact "
                          "fails validation, instead of refusing to start")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (registry.get_smoke_config(args.arch) if args.smoke
            else registry.get_config(args.arch))
@@ -237,6 +239,7 @@ def main(argv=None):
     if args.trace_out:
         n = trace.export(args.trace_out)
         print(f"  trace: {n} events -> {args.trace_out}")
+    return eng, reqs
 
 
 if __name__ == "__main__":

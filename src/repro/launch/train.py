@@ -1,12 +1,14 @@
 """Training launcher (deliverable (b) driver).
 
-CPU-scale by default: pick an arch (full or smoke config), a small batch,
-and run the fault-tolerant Trainer on the synthetic C4 pipeline. On a real
-TPU fleet the same entrypoint runs under `jax.distributed` with the
-production mesh; here the mesh is the single-device local mesh.
+Pick an arch (full config, or ``--smoke`` for the reduced one), a batch,
+and run the fault-tolerant Trainer on the synthetic C4 pipeline. The same
+entrypoint runs on the CPU (Pallas kernels interpreted) and on TPU chips;
+``--use-mesh`` places state on a mesh of every visible device, and
+``--multipod`` joins a multi-process ``jax.distributed`` job.
 
 Usage:
   python -m repro.launch.train --arch llama_60m --smoke --steps 200
+  python -m repro.launch.train --arch llama_1b --exec-mode fused --steps 3
   python -m repro.launch.train --arch llama_60m --smoke --mode dense   # baseline
   python -m repro.launch.train --arch yi_34b --smoke --optimizer adam8bit
   python -m repro.launch.train --arch llama_60m --smoke --steps 20 \
@@ -20,6 +22,7 @@ import dataclasses
 
 from repro.configs.base import (OptimizerConfig, ShardingConfig, TrainConfig,
                                 ParamConfig)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.obs import trace as obs_trace
 from repro.train.trainer import Trainer
@@ -105,8 +108,9 @@ def main(argv=None):
                     help="checkpoint rollbacks tolerated before the "
                          "trainer gives up on a persistent divergence")
     ap.add_argument("--multipod", action="store_true",
-                    help="initialize jax.distributed from JAX_* env vars "
-                         "(scripts/launch_multipod.sh sets them)")
+                    help="initialize jax.distributed from the "
+                         "JAX_COORDINATOR_ADDRESS, JAX_NUM_PROCESSES and "
+                         "JAX_PROCESS_ID env vars")
     ap.add_argument("--use-mesh", action="store_true",
                     help="run under the named local mesh and place state "
                          "via the repro.dist.sharding spec engine")
@@ -123,6 +127,7 @@ def main(argv=None):
         ap.error("--fsdp shards state via the spec engine and needs "
                  "--use-mesh (or a multipod mesh wired in code)")
 
+    enable_compile_cache()
     if args.multipod:
         import os
         import jax
@@ -157,7 +162,7 @@ def main(argv=None):
     if args.trace_out:
         n = trace.export(args.trace_out)
         print(f"trace: {n} events -> {args.trace_out}")
-    return trainer
+    return trainer, state
 
 
 if __name__ == "__main__":

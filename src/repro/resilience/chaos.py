@@ -33,7 +33,9 @@ reproducible. Injections are counted on the bound registry as
 from __future__ import annotations
 
 import os
+import struct
 import time
+import zipfile
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -64,16 +66,25 @@ class Fault:
 
 
 def corrupt_npz(path: str, *, seed: int = 0, n_bytes: int = 16) -> int:
-    """Flip ``n_bytes`` bytes in the middle of ``path`` in place (XOR
-    0xFF at a deterministic offset). Returns the offset. Used by the
-    ``ckpt_corrupt`` fault and the fault-tolerance tests."""
-    size = os.path.getsize(path)
+    """Flip up to ``n_bytes`` bytes of one array's stored bytes in
+    ``path`` in place (XOR 0xFF at a deterministic offset). Returns the
+    offset. The flip stays inside a zip member's data: zip readers ignore
+    local-header fields (timestamps, extra fields), so a flip there would
+    be damage no reader can see. Used by the ``ckpt_corrupt`` fault and
+    the fault-tolerance tests."""
+    with zipfile.ZipFile(path) as z:
+        infos = [i for i in z.infolist() if i.compress_size > 0]
     rng = np.random.default_rng(np.uint64(seed))
-    # stay away from the zip end-of-central-directory record at the tail
-    off = int(rng.integers(size // 4, max(size // 4 + 1, size // 2)))
+    info = infos[int(rng.integers(len(infos)))]
     with open(path, "r+b") as f:
+        # local file header: 30 fixed bytes, then name and extra field
+        f.seek(info.header_offset + 26)
+        name_len, extra_len = struct.unpack("<HH", f.read(4))
+        start = info.header_offset + 30 + name_len + extra_len
+        n = min(n_bytes, info.compress_size)
+        off = start + int(rng.integers(info.compress_size - n + 1))
         f.seek(off)
-        raw = f.read(n_bytes)
+        raw = f.read(n)
         f.seek(off)
         f.write(bytes(b ^ 0xFF for b in raw))
     return off

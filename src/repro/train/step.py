@@ -217,7 +217,6 @@ def make_compressed_dp_step(cfg: ModelConfig, api: ModelApi,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import compat
     from repro.dist.compression import psum_tree
 
     loss_fn = make_loss_fn(cfg, api, "none", aux_coef)
@@ -249,7 +248,7 @@ def make_compressed_dp_step(cfg: ModelConfig, api: ModelApi,
         return jax.tree.map(spec, tree)
 
     def step(params, opt_state, consts, batch):
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(specs_like(params), specs_like(opt_state),
                       specs_like(consts), specs_like(batch, True)),

@@ -40,6 +40,7 @@ Resilience (repro.resilience; tests/test_fault_tolerance.py):
 from __future__ import annotations
 
 import contextlib
+import functools
 import signal
 import sys
 import time
@@ -195,7 +196,8 @@ class Trainer:
             "train.tokens_per_sec", help="tokens / (dispatch + sync) time")
         self._g_mfu = self.obs.gauge(
             "train.mfu", help="6ND model-FLOPs utilisation vs chip peak "
-            "(analysis.roofline.train_mfu)")
+            "(analysis.roofline.train_mfu); unset = not measured (CPU)")
+        self._peaks = roofline.peaks_for(jax.devices()[0])
         self._h_step = self.obs.histogram(
             "train.step_ms", buckets=obs_metrics.ms_buckets())
         phase_h = self.obs.histogram(
@@ -228,9 +230,13 @@ class Trainer:
         only exist once the param tree does, and the step closes over
         them to pin gradients to the sharded layout (reduce-scatter)."""
         tc = self.tc
+        # params and optimizer state are donated: each step's new state
+        # takes the old one's buffers, so one copy of the state is resident
+        # (the loop never reads a state it has stepped past)
+        jit = functools.partial(jax.jit, donate_argnums=(0, 1))
         if tc.sharding.update_mode == "per_layer":
             from repro.train import perlayer
-            return jax.jit(perlayer.make_perlayer_train_step(
+            return jit(perlayer.make_perlayer_train_step(
                 self.cfg, self.api, self.optimizer,
                 remat=tc.sharding.remat,
                 grad_accum=tc.sharding.grad_accum,
@@ -244,10 +250,10 @@ class Trainer:
                 and "pod" in self.mesh.axis_names:
             # int8-compressed cross-pod DP (dist/compression.py); wire
             # counters land on this trainer's registry -> metrics JSONL
-            return jax.jit(step_lib.make_compressed_dp_step(
+            return jit(step_lib.make_compressed_dp_step(
                 self.cfg, self.api, self.optimizer, self.mesh,
                 obs=self.obs))
-        return jax.jit(step_lib.make_train_step(
+        return jit(step_lib.make_train_step(
             self.cfg, self.api, self.optimizer,
             remat=tc.sharding.remat, grad_accum=tc.sharding.grad_accum,
             grad_specs=grad_specs))
@@ -256,38 +262,57 @@ class Trainer:
     def init_state(self) -> TrainerState:
         key = jax.random.PRNGKey(self.tc.seed)
         params, consts = self.api.init(self.cfg, key, seed=self.tc.seed)
-        opt_state = self.optimizer.init(params)
-        return TrainerState(params, opt_state, consts, step=0)
+        if self.mesh is None:
+            return TrainerState(params, self.optimizer.init(params), consts)
+        # on a mesh the optimizer state is built already sharded: a
+        # model's whole state need not fit on one device (LLaMA-7B's does
+        # not fit on one v5e chip)
+        from repro.dist import sharding as dist_sharding
+        p_specs, o_specs, c_specs = self._specs(params, consts)
+        params = dist_sharding.place(params, self.mesh, p_specs)
+        opt_state = jax.jit(
+            self.optimizer.init,
+            out_shardings=dist_sharding.named_shardings(self.mesh, o_specs)
+        )(params)
+        return TrainerState(
+            params, opt_state,
+            dist_sharding.place(consts, self.mesh, c_specs))
+
+    def _specs(self, params, consts):
+        """(param, optimizer-state, const) PartitionSpec trees for the
+        mesh, per the dist.sharding spec engine. Optimizer moments inherit
+        the matching param leaf's spec; with ``sharding.fsdp`` every tree
+        also shards over the fsdp axis."""
+        from repro.dist import sharding as dist_sharding
+        sh = self.tc.sharding
+        fsdp_axes = (sh.fsdp_axis,) if sh.fsdp else ()
+        p_specs = dist_sharding.param_specs(params, self.mesh,
+                                            fsdp_axes=fsdp_axes)
+        o_specs = dist_sharding.opt_state_specs(
+            jax.eval_shape(self.optimizer.init, params), p_specs, self.mesh,
+            fsdp_axes=fsdp_axes)
+        c_specs = dist_sharding.param_specs(consts, self.mesh,
+                                            fsdp_axes=fsdp_axes)
+        return p_specs, o_specs, c_specs
 
     def _mesh_ctx(self):
         return self.mesh if self.mesh is not None else contextlib.nullcontext()
 
     def _place(self, state: TrainerState) -> TrainerState:
-        """Place state on the mesh per the dist.sharding spec engine (no-op
-        without a mesh). Params/consts get the param rules; optimizer
-        moments inherit the matching param leaf's spec. With
-        ``sharding.fsdp`` the specs additionally shard over the fsdp axis
-        and the train step is rebuilt to pin gradients to that layout."""
+        """Place state on the mesh (no-op without one; a no-op copy for
+        state :meth:`init_state` already placed). With ``sharding.fsdp``
+        the train step is rebuilt to pin gradients to the sharded
+        layout."""
         if self.mesh is None:
             return state
         from repro.dist import sharding as dist_sharding
-        mesh = self.mesh
-        sh = self.tc.sharding
-        fsdp_axes = (sh.fsdp_axis,) if sh.fsdp else ()
-        p_specs = dist_sharding.param_specs(state.params, mesh,
-                                            fsdp_axes=fsdp_axes)
-        if sh.fsdp:
+        p_specs, o_specs, c_specs = self._specs(state.params, state.consts)
+        if self.tc.sharding.fsdp:
             self._train_step = self._build_train_step(grad_specs=p_specs)
         return TrainerState(
-            dist_sharding.place(state.params, mesh, p_specs),
-            dist_sharding.place(
-                state.opt_state, mesh,
-                dist_sharding.opt_state_specs(state.opt_state, p_specs,
-                                              mesh, fsdp_axes=fsdp_axes)),
-            dist_sharding.place(
-                state.consts, mesh,
-                dist_sharding.param_specs(state.consts, mesh,
-                                          fsdp_axes=fsdp_axes)),
+            dist_sharding.place(state.params, self.mesh, p_specs),
+            dist_sharding.place(state.opt_state, self.mesh, o_specs),
+            dist_sharding.place(state.consts, self.mesh, c_specs),
             state.step)
 
     def save(self, state: TrainerState, background: Optional[bool] = None) -> None:
@@ -455,16 +480,18 @@ class Trainer:
             if "grad_norm" in row:
                 self._g_gnorm.set(row["grad_norm"])
             self._g_tps.set(tokens_per_step / dt if dt > 0 else 0.0)
-            self._g_mfu.set(roofline.train_mfu(self.cfg, tokens_per_step,
-                                               dt, self._chips))
+            if self._peaks is not None:
+                self._g_mfu.set(roofline.train_mfu(
+                    self.cfg, tokens_per_step, dt, self._peaks, self._chips))
             if state.step % tc.log_every == 0 or state.step == total:
                 # log line reads back from the registry — the gauges ARE
                 # the trainer's reporting surface, not a side channel
                 self.log(f"[step {state.step:5d}] "
                          f"loss={self._g_loss.value:.4f} "
                          f"lr={self._g_lr.value or 0:.2e} {dt*1e3:.0f}ms "
-                         f"{self._g_tps.value:.0f}tok/s "
-                         f"mfu={self._g_mfu.value:.4f}"
+                         f"{self._g_tps.value:.0f}tok/s"
+                         + (f" mfu={self._g_mfu.value:.4f}"
+                            if self._g_mfu.value is not None else "")
                          + (" STRAGGLER" if slow else ""))
                 if self.metrics_out:
                     self.obs.write_jsonl(self.metrics_out,

@@ -1,14 +1,10 @@
-"""Shared test setup: put ``src`` on sys.path and install the jax
-forward-compat shims (``jax.shard_map``, ``jax.sharding.AxisType``,
-``make_mesh(axis_types=...)``) before any test module touches jax."""
+"""Shared test setup: put ``src`` and the repo root on sys.path."""
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 # repo root, so tests can import the benchmarks/ modules they exercise
 sys.path.insert(1, os.path.join(os.path.dirname(__file__), os.pardir))
-
-import repro.dist  # noqa: E402,F401  (import side effect: compat shims)
 
 
 def pytest_report_header(config):

@@ -1,4 +1,4 @@
-"""Tests for the repro.dist subsystem: compat layer, spec engine,
+"""Tests for the repro.dist subsystem: mesh construction, spec engine,
 compressed collectives, and the compressed-DP step round trip."""
 import jax
 import jax.numpy as jnp
@@ -6,34 +6,13 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat, compression, sharding as shl
+from repro.dist import compression, sharding as shl
 from repro.models import registry
 
 
 def _pod_mesh():
-    return compat.make_mesh((1,), ("pod",),
-                            axis_types=(compat.AxisType.Auto,))
-
-
-# ---------------------------------------------------------------------------
-# compat
-# ---------------------------------------------------------------------------
-
-def test_compat_shard_map_accepts_both_check_spellings():
-    mesh = _pod_mesh()
-    x = jnp.arange(8, dtype=jnp.float32)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        f = compat.shard_map(lambda v: jax.lax.psum(v, "pod"), mesh=mesh,
-                             in_specs=P(), out_specs=P(), **kw)
-        np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x))
-
-
-def test_forward_compat_names_installed():
-    # conftest imports repro.dist, which installs the shims
-    assert hasattr(jax, "shard_map")
-    assert hasattr(jax.sharding, "AxisType")
-    jax.make_mesh((1,), ("pod",),
-                  axis_types=(jax.sharding.AxisType.Auto,))
+    return jax.make_mesh((1,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 # ---------------------------------------------------------------------------
@@ -60,12 +39,12 @@ def test_spec_sltrain_factor_leaves(mesh2):
     assert _spec(("layers", "k0", "attn", "wq", "B"), (4, 64, 8),
                  mesh2) == P(None, None, None)
     sA = _spec(("layers", "k0", "attn", "wq", "A"), (4, 8, 64), mesh2)
-    assert sA[-1] == ("model",)
+    assert sA[-1] == "model"
     sv = _spec(("layers", "k0", "attn", "wq", "v"), (4, 64, 3), mesh2)
-    assert sv[1] == ("model",)
+    assert sv[1] == "model"
     sc = _spec(("layers", "k0", "attn", "wq", "cols"), (4, 64, 3), mesh2,
                jnp.int32)
-    assert sc[1] == ("model",)
+    assert sc[1] == "model"
 
 
 def test_spec_dense_and_replicated_leaves(mesh2):
@@ -107,7 +86,7 @@ def test_param_specs_iid_support_not_row_sharded(mesh2):
     # the row-balanced form (no rows sibling) still row-shards
     rb = shl.param_specs({"wq": {"v": sds((64, 3), jnp.bfloat16),
                                  "cols": sds((64, 3), jnp.int32)}}, mesh2)
-    assert rb["wq"]["v"][0] == ("model",)
+    assert rb["wq"]["v"][0] == "model"
 
 
 def test_param_specs_match_tree_and_cover_moe():
@@ -155,7 +134,7 @@ def test_cache_specs_batch_and_heads():
     specs = shl.cache_specs(cache, mesh, batch_axes=("data",))
     for _, spec in jax.tree_util.tree_flatten_with_path(
             specs, is_leaf=lambda x: isinstance(x, P))[0]:
-        assert spec[-4] == ("data",)       # batch dim sharded
+        assert spec[-4] == "data"       # batch dim sharded
         assert spec[-3] is None            # seq replicated (not seq_sharded)
 
 
@@ -173,7 +152,7 @@ def test_cache_specs_paged_heads_sharded_blocks_replicated():
         specs, is_leaf=lambda x: isinstance(x, P))[0]
     assert flat, "empty paged cache spec tree"
     for path, spec in flat:
-        assert spec[-2] == ("model",), (path, spec)   # heads sharded (TP)
+        assert spec[-2] == "model", (path, spec)   # heads sharded (TP)
         assert spec[-4] is None and spec[-3] is None  # pages replicated
         assert spec[-1] is None
     # indivisible heads fall back to replication, never an error. The spec
@@ -198,7 +177,7 @@ def test_cache_specs_paged_heads_sharded_blocks_replicated():
     for _, spec in jax.tree_util.tree_flatten_with_path(
             shl.cache_specs(cache4, _TPMesh(), paged=True),
             is_leaf=lambda x: isinstance(x, P))[0]:
-        assert spec[-2] == ("model",)
+        assert spec[-2] == "model"
 
 
 # ---------------------------------------------------------------------------
@@ -213,7 +192,7 @@ def test_psum_tree_compressed_matches_exact():
         "small": jnp.asarray(rng.standard_normal(16), jnp.float32),
         "ints": jnp.arange(2048, dtype=jnp.int32),
     }
-    run = lambda compress: compat.shard_map(
+    run = lambda compress: jax.shard_map(
         lambda t: compression.psum_tree(t, "pod", compress=compress),
         mesh=mesh, in_specs=(jax.tree.map(lambda _: P(), tree),),
         out_specs=jax.tree.map(lambda _: P(), tree), check_vma=False)(tree)
@@ -258,8 +237,8 @@ def test_compressed_dp_step_cpu_mesh_roundtrip():
     opt = opt_lib.make(OptimizerConfig(lr=1e-3, warmup_steps=1,
                                        total_steps=4))
     opt_state = opt.init(params)
-    mesh = compat.make_mesh((1,), ("pod",),
-                            axis_types=(compat.AxisType.Auto,))
+    mesh = jax.make_mesh((1,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     step = jax.jit(step_lib.make_compressed_dp_step(cfg, api, opt, mesh))
     data = SyntheticC4(cfg.vocab_size, 32, 4, seed=0)
     p0 = jax.tree.leaves(params)[0]
@@ -391,3 +370,34 @@ def test_wire_model_matches_hlo_measured_collectives():
     assert m, out.stdout
     ratio = float(m.group(1))
     assert 0.7 <= ratio <= 1.3, (ratio, out.stdout)
+
+
+# ---------------------------------------------------------------------------
+# Trainer on the local mesh: sharded init + placement + donated steps
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("optimizer", ["adamw", "adam8bit"])
+def test_trainer_fsdp_on_local_mesh_matches_no_mesh(optimizer, tmp_path):
+    """FSDP on the local mesh (every visible device) builds its optimizer
+    state already sharded and must train exactly like the unplaced run."""
+    from repro.configs.base import (OptimizerConfig, ShardingConfig,
+                                    TrainConfig)
+    from repro.train.trainer import Trainer
+
+    losses = {}
+    for name, mesh in (("plain", None), ("mesh", shl.make_local_mesh())):
+        tc = TrainConfig(
+            model=registry.get_smoke_config("llama_60m"),
+            optim=OptimizerConfig(name=optimizer, lr=1e-3, warmup_steps=1,
+                                  total_steps=2),
+            sharding=ShardingConfig(fsdp=mesh is not None),
+            global_batch=4, seq_len=32, steps=2, log_every=100,
+            ckpt_dir=str(tmp_path / name))
+        tr = Trainer(tc, mesh=mesh, log_fn=lambda *_: None)
+        state = tr.run()
+        losses[name] = [h["loss"] for h in tr.metrics_history]
+        if mesh is not None:
+            leaf = jax.tree.leaves(state.opt_state)[0]
+            assert isinstance(leaf.sharding, jax.sharding.NamedSharding)
+            assert leaf.sharding.mesh.shape == mesh.shape
+    np.testing.assert_allclose(losses["mesh"], losses["plain"], rtol=1e-5)

@@ -224,7 +224,7 @@ def test_fused_tile_consts_shard_nnt_over_model():
         if name in ("rows_t", "cols_t", "perm"):
             seen.add(name)
             # spec covers (…stack, nkt, nnt, cap): only nnt carries model
-            assert spec[-2] in (("model",), None), (path, spec)
+            assert spec[-2] in ("model", None), (path, spec)
             assert all(s is None for i, s in enumerate(spec)
                        if i != len(spec) - 2), (path, spec)
     assert seen == {"rows_t", "cols_t", "perm"}

@@ -389,7 +389,8 @@ def test_trainer_gauges_spans_and_jsonl(tmp_path):
         t.metrics_history[-1]["loss"])
     assert snap["train.lr"]["value"] == pytest.approx(
         t.metrics_history[-1]["lr"])
-    assert 0 < snap["train.mfu"]["value"] < 1
+    # no published peaks for the CPU: MFU stays unset ("not measured")
+    assert snap["train.mfu"]["value"] is None
     assert snap["train.tokens_per_sec"]["value"] > 0
     assert snap["train.step_ms"]["count"] == 3
     for phase in ("data", "dispatch", "sync"):

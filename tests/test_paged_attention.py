@@ -271,7 +271,7 @@ def test_cache_specs_kernel_matches_gather_layout(gqa_model):
     s_paged = shl.cache_specs(cache, mesh, paged=True, attn_kernel="paged")
     assert s_gather == s_paged
     leaf = jax.tree.leaves(s_paged, is_leaf=lambda x: hasattr(x, "index"))[0]
-    assert leaf[-2:] == (("model",), None)   # heads sharded, hd replicated
+    assert leaf[-2:] == ("model", None)   # heads sharded, hd replicated
     assert leaf[-4:-2] == (None, None)       # block dims replicated
 
 

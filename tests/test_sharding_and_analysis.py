@@ -128,10 +128,43 @@ ENTRY %main (p: f32[1024]) -> f32[1024] {
 def test_roofline_terms_and_bottleneck():
     rl = roofline.Roofline(flops=1e15, hbm_bytes=1e12, wire_bytes=1e12,
                            chips=256, model_flops=5e14)
-    assert rl.t_compute == pytest.approx(1e15 / (256 * roofline.PEAK_FLOPS))
+    assert rl.t_compute == pytest.approx(
+        1e15 / (256 * roofline.PEAKS[roofline.V5E].flops))
     assert rl.bottleneck in ("compute", "memory", "collective")
     assert 0 < rl.roofline_fraction <= 1.0
     assert rl.useful_flops_ratio == pytest.approx(0.5)
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,flops", [
+    ("tpu", "TPU v5 lite", 197e12),     # v5e, the table's own row
+    ("cpu", "cpu", None),               # no device metric on the CPU
+])
+def test_peaks_by_device_kind(platform, kind, flops):
+    peaks = roofline.peaks_for(_Dev(platform, kind))
+    assert (peaks and peaks.flops) == flops
+    if peaks is not None:
+        assert peaks.hbm_bw == 819e9 and peaks.source
+
+
+def test_peaks_unknown_accelerator_raises():
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks_for(_Dev("tpu", "TPU v99"))
+
+
+def test_train_mfu_against_peaks():
+    cfg = registry.get_config("yi_34b")
+    peaks = roofline.PEAKS[roofline.V5E]
+    tokens = 4096
+    flops = roofline.model_flops(cfg, tokens, "train")
+    # a step that delivers exactly half of two chips' peak
+    dt = flops / (0.5 * 2 * peaks.flops)
+    assert roofline.train_mfu(cfg, tokens, dt, peaks, chips=2) == \
+        pytest.approx(0.5)
 
 
 def test_model_flops_moe_uses_active_params():

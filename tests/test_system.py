@@ -306,3 +306,29 @@ def test_compressed_dp_step_trains():
         losses.append(float(m["loss"]))
     assert all(np.isfinite(l) for l in losses)
     assert losses[-1] < losses[0], losses
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(env_dir, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the only cache directory
+    (JAX reads it; nothing else is set); otherwise one fixed directory in
+    the checkout."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
