@@ -41,7 +41,7 @@ echo "== per-layer smoke: update_mode=per_layer 8-bit 3-step train =="
 OBS_DIR="$(mktemp -d)"
 python -m repro.launch.train --arch llama_60m --smoke --mode sltrain \
   --update-mode per_layer --optimizer adam8bit --steps 3 --batch 2 --seq 16 \
-  --log-every 1 --ckpt-dir "$(mktemp -d)" --layer-timing \
+  --log-every 1 --ckpt-dir "$(mktemp -d)" \
   --metrics-out "$OBS_DIR/train.jsonl" --trace-out "$OBS_DIR/train_trace.json"
 
 echo "== serve smoke: paged KV engine, 3 staggered requests =="

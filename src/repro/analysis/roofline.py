@@ -263,13 +263,3 @@ def model_flops(cfg, n_tokens: int, kind: str = "train") -> float:
     mult = 6.0 if kind == "train" else 2.0
     return mult * active * n_tokens
 
-
-def train_mfu(cfg, n_tokens: int, dt_s: float, peaks: DevicePeaks,
-              chips: int = 1) -> float:
-    """Model FLOPs utilisation of one training step: the 6·N·D model
-    FLOPs actually delivered per second, as a fraction of the chips' peak
-    (``peaks.flops`` each). The trainer publishes this per step as the
-    ``train.mfu`` gauge, on a device with published peaks only."""
-    if dt_s <= 0:
-        return 0.0
-    return model_flops(cfg, n_tokens, "train") / dt_s / (chips * peaks.flops)

@@ -21,6 +21,7 @@ from repro.core import support as support_lib
 from repro.kernels import adam8bit as adam8bit_kernel
 from repro.kernels import sddmm as sddmm_kernel
 from repro.kernels import sl_matmul as sl_kernel
+from repro.obs import trace as obs_trace
 
 
 def interpret_mode(interpret: bool | None = None) -> bool:
@@ -93,11 +94,14 @@ def prepare_tile_consts(rows: np.ndarray, cols: np.ndarray, d_in: int,
     capacity ``pad`` must be the deterministic ``support.tile_cap`` bound
     so abstract dry-run shapes match concrete init and per-layer consts
     stack; raises ``ValueError`` when the sampled support exceeds it
-    (callers re-sample on host)."""
-    rt, ct, perm = _tile_index_arrays(rows, cols, d_in, d_out, tile_r,
-                                      tile_c, pad)
-    return {"rows_t": jnp.asarray(rt), "cols_t": jnp.asarray(ct),
-            "perm": jnp.asarray(perm)}
+    (callers re-sample on host). The host build and the transfer are the
+    process recorder's ``sl.tile_tables`` span."""
+    with obs_trace.get_trace().span("sl.tile_tables", cat="init",
+                                    d_in=d_in, d_out=d_out):
+        rt, ct, perm = _tile_index_arrays(rows, cols, d_in, d_out, tile_r,
+                                          tile_c, pad)
+        return {"rows_t": jnp.asarray(rt), "cols_t": jnp.asarray(ct),
+                "perm": jnp.asarray(perm)}
 
 
 def _pad2(x, mult_r, mult_c):
@@ -267,15 +271,17 @@ def _fused_grads(x, B, A, v_t, rows_t, cols_t, scale, dy):
     dA = (scale * _tok_dot(xB, dyf.astype(f32))).astype(A.dtype)
     dyA = jnp.matmul(dyf, A.T, preferred_element_type=f32)  # (M, r) f32
     dB = (scale * _tok_dot(xf.astype(f32), dyA)).astype(B.dtype)
-    dv_t = sddmm(xf, dyf, rows_t, cols_t)                 # f32 tiles
+    with jax.named_scope("dv"):
+        dv_t = sddmm(xf, dyf, rows_t, cols_t)             # f32 tiles
     # dx = dy @ W^T: reuse the fused kernel on the transposed factors. The
     # support transpose is (cols_t, rows_t) tiles transposed in the grid —
     # equivalently run sl_matmul with swapped tile axes.
-    vt_T = jnp.swapaxes(v_t, 0, 1)
-    rt_T = jnp.swapaxes(cols_t, 0, 1)
-    ct_T = jnp.swapaxes(rows_t, 0, 1)
-    dx = sl_matmul(dyf, A.T, B.T, vt_T, rt_T, ct_T, scale
-                   ).reshape(x.shape).astype(x.dtype)
+    with jax.named_scope("dx"):
+        vt_T = jnp.swapaxes(v_t, 0, 1)
+        rt_T = jnp.swapaxes(cols_t, 0, 1)
+        ct_T = jnp.swapaxes(rows_t, 0, 1)
+        dx = sl_matmul(dyf, A.T, B.T, vt_T, rt_T, ct_T, scale
+                       ).reshape(x.shape).astype(x.dtype)
     return dx, dB, dA, dv_t
 
 
@@ -317,13 +323,17 @@ def sl_linear(x, B, A, v, rows_t, cols_t, perm, scale):
     optimizer/checkpoints see. The tile gather (fwd) and scatter (bwd)
     happen inside the jit, so only the layout-independent flat v is ever
     state; tile order is a pure function of the int consts from
-    ``prepare_tile_consts``."""
-    return sl_matmul(x, B, A, _gather_tiles(v, perm), rows_t, cols_t, scale)
+    ``prepare_tile_consts``. Its device ops carry the pass they belong to
+    in their scope: ``fwd``, and in the backward ``dx`` and ``dv``."""
+    with jax.named_scope("fwd"):
+        return sl_matmul(x, B, A, _gather_tiles(v, perm), rows_t, cols_t,
+                         scale)
 
 
 def _sl_linear_fwd(x, B, A, v, rows_t, cols_t, perm, scale):
-    v_t = _gather_tiles(v, perm)
-    y = sl_matmul(x, B, A, v_t, rows_t, cols_t, scale)
+    with jax.named_scope("fwd"):
+        v_t = _gather_tiles(v, perm)
+        y = sl_matmul(x, B, A, v_t, rows_t, cols_t, scale)
     # residuals stay factored-sized (Alg. 1): v_t is nnz+pad floats, never
     # the (d_in, d_out) dense W
     return y, (x, B, A, v, v_t, rows_t, cols_t, perm)
@@ -335,10 +345,11 @@ def _sl_linear_bwd(scale, res, dy):
     # scatter the f32 tile grads back through perm onto the flat layout;
     # every valid perm entry appears exactly once (tile_layout invariant)
     # so the add is exact, padding rides the clipped index with a 0 value
-    pf = perm.reshape(-1)
-    flat = jnp.where(pf >= 0, dv_t.reshape(-1), 0.0)
-    dv = jnp.zeros((v.size,), jnp.float32).at[
-        jnp.clip(pf, 0, v.size - 1)].add(flat)
+    with jax.named_scope("dv"):
+        pf = perm.reshape(-1)
+        flat = jnp.where(pf >= 0, dv_t.reshape(-1), 0.0)
+        dv = jnp.zeros((v.size,), jnp.float32).at[
+            jnp.clip(pf, 0, v.size - 1)].add(flat)
     return (dx, dB, dA, dv.reshape(v.shape).astype(v.dtype),
             None, None, None)
 

@@ -12,13 +12,15 @@ Usage:
   python -m repro.launch.train --arch llama_60m --smoke --mode dense   # baseline
   python -m repro.launch.train --arch yi_34b --smoke --optimizer adam8bit
   python -m repro.launch.train --arch llama_60m --smoke --steps 20 \
-      --update-mode per_layer --layer-timing \
+      --update-mode per_layer \
       --metrics-out /tmp/train.jsonl --trace-out /tmp/train_trace.json
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+
+import jax
 
 from repro.configs.base import (OptimizerConfig, ShardingConfig, TrainConfig,
                                 ParamConfig)
@@ -90,12 +92,9 @@ def main(argv=None):
                     help="append registry snapshot JSONL lines here (one "
                          "per log interval; repro.obs.metrics)")
     ap.add_argument("--trace-out", default=None,
-                    help="write a Chrome-trace JSON of per-step spans "
-                         "(data/dispatch/sync; repro.obs.trace)")
-    ap.add_argument("--layer-timing", action="store_true",
-                    help="with --update-mode per_layer: record per-layer "
-                         "update wall time via ordered io_callback into "
-                         "train.perlayer.layer_update_ms")
+                    help="write the process recorder's Chrome-trace JSON: "
+                         "step spans (data/dispatch/sync/readback), tile "
+                         "tables and JAX's compile events (repro.obs.trace)")
     ap.add_argument("--jax-profile-dir", default=None,
                     help="also record a jax.profiler trace into this dir "
                          "for the duration of the run")
@@ -130,7 +129,6 @@ def main(argv=None):
     enable_compile_cache()
     if args.multipod:
         import os
-        import jax
         jax.distributed.initialize(
             coordinator_address=os.environ["JAX_COORDINATOR_ADDRESS"],
             num_processes=int(os.environ["JAX_NUM_PROCESSES"]),
@@ -147,16 +145,14 @@ def main(argv=None):
         chaos = ChaosEngine.parse(args.chaos, seed=args.seed)
 
     tc = build_train_config(args)
-    trace = obs_trace.Trace(
-        enabled=bool(args.trace_out or args.jax_profile_dir),
-        jax_profile_dir=args.jax_profile_dir)
-    trace.start()
-    trainer = Trainer(tc, mesh=mesh, trace=trace,
-                      metrics_out=args.metrics_out,
-                      layer_timing=args.layer_timing,
+    trace = obs_trace.get_trace()
+    if args.jax_profile_dir:
+        jax.profiler.start_trace(args.jax_profile_dir)
+    trainer = Trainer(tc, mesh=mesh, metrics_out=args.metrics_out,
                       chaos=chaos, max_rollbacks=args.max_rollbacks)
     state = trainer.run()
-    trace.stop()
+    if args.jax_profile_dir:
+        jax.profiler.stop_trace()
     print(f"final step {state.step}: "
           f"loss={trainer.metrics_history[-1]['loss']:.4f}")
     if args.trace_out:

@@ -13,8 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.models.common import (Builder, apply_linear, constrain, rms_norm,
-                                 rope, softcap)
+from repro.models.common import (Builder, constrain, rms_norm, rope,
+                                 scoped_linear, softcap)
 
 
 def init_attention(b: Builder, cfg: ModelConfig, cross: bool = False):
@@ -152,7 +152,7 @@ def apply_attention(cfg: ModelConfig, params, consts, x, *, pos_offset=0,
     Returns (y, new_cache)."""
     hd = cfg.resolved_head_dim
     nh, nkv = cfg.n_heads, cfg.n_kv_heads
-    lin = lambda n, t: apply_linear(cfg, params[n], consts.get(n, {}), t)
+    lin = lambda n, t: scoped_linear(cfg, params, consts, n, t)
     bsz, sq = x.shape[0], x.shape[1]
 
     q = _split_heads(lin("wq", x), nh, hd)

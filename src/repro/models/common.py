@@ -125,6 +125,13 @@ def apply_linear(cfg: ModelConfig, params, consts, x, adapted: bool = True):
     return y
 
 
+def scoped_linear(cfg: ModelConfig, params, consts, name: str, x):
+    """:func:`apply_linear` of the linear ``params[name]`` under a
+    ``jax.named_scope`` of its name, so its device ops carry it."""
+    with jax.named_scope(name):
+        return apply_linear(cfg, params[name], consts.get(name, {}), x)
+
+
 # ---------------------------------------------------------------------------
 # Normalization / activations / rope
 # ---------------------------------------------------------------------------

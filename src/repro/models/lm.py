@@ -57,10 +57,13 @@ def _apply_block(cfg: ModelConfig, p, c, x, *, window: int, cache=None,
     act = "gelu" if cfg.family in ("gemma2", "vlm") else "silu"
     norm = lambda t, w: rms_norm(t, w, cfg.norm_eps, plus_one=plus_one)
     h = norm(x, p["ln_attn"])
-    a, new_cache = attention.apply_attention(
-        cfg, p["attn"], c.get("attn", {}), h, pos_offset=pos_offset,
-        causal=True, window=window, cache=cache, cache_index=cache_index,
-        block_table=block_table, prefill=prefill)
+    # scopes name each linear's device ops by its role (attn/wq ...
+    # mlp/down); the layers run in a scan, so no layer index is needed
+    with jax.named_scope("attn"):
+        a, new_cache = attention.apply_attention(
+            cfg, p["attn"], c.get("attn", {}), h, pos_offset=pos_offset,
+            causal=True, window=window, cache=cache, cache_index=cache_index,
+            block_table=block_table, prefill=prefill)
     if cfg.use_post_norms:
         a = norm(a, p["ln_attn_post"])
     x = x + a
@@ -69,7 +72,8 @@ def _apply_block(cfg: ModelConfig, p, c, x, *, window: int, cache=None,
     if "moe" in p:
         m, aux = mlp.apply_moe(cfg, p["moe"], c.get("moe", {}), h)
     else:
-        m = mlp.apply_mlp(cfg, p["mlp"], c.get("mlp", {}), h, act=act)
+        with jax.named_scope("mlp"):
+            m = mlp.apply_mlp(cfg, p["mlp"], c.get("mlp", {}), h, act=act)
     if cfg.use_post_norms:
         m = norm(m, p["ln_mlp_post"])
     return x + m, new_cache, aux

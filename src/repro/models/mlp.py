@@ -7,7 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import Builder, apply_linear, gelu, silu
+from repro.models.common import (Builder, apply_linear, gelu,
+                                 scoped_linear, silu)
 
 
 # ---------------------------------------------------------------------------
@@ -29,7 +30,7 @@ def init_mlp(b: Builder, cfg: ModelConfig, d_ff: int = 0, gated: bool = True):
 
 
 def apply_mlp(cfg: ModelConfig, params, consts, x, act: str = "silu"):
-    lin = lambda n, t: apply_linear(cfg, params[n], consts.get(n, {}), t)
+    lin = lambda n, t: scoped_linear(cfg, params, consts, n, t)
     a = {"silu": silu, "gelu": gelu}[act]
     if "gate" in params:
         return lin("down", a(lin("gate", x)) * lin("up", x))

@@ -8,16 +8,14 @@ at the call site (``float(v)`` works on concrete jax arrays and forces
 the host transfer right there); a jax *tracer* cannot be coerced, so
 recording inside a jit trace fails loudly with a ``TypeError`` instead of
 silently leaking the tracer into host state. That is the jit-safety
-contract: record around jitted calls, never inside them (inside jit, use
-``jax.experimental.io_callback`` to hop to host first — see
-train/perlayer.py's layer timing).
+contract: record around jitted calls, never inside them.
 
 Instrument taxonomy (see ``repro.obs.__init__`` for the full contract):
 
 * :class:`Counter` — monotonically non-decreasing totals (dispatches,
   tokens, requests). ``inc(n)``; ``reset()`` zeroes (bench warmup).
 * :class:`Gauge` — last-written point-in-time values (loss, tokens/sec,
-  MFU, queue depth). ``set(v)``.
+  queue depth). ``set(v)``.
 * :class:`Histogram` — fixed-bucket distributions (TTFT, step latency).
   Only per-bucket counts + sum are retained, never samples, so memory is
   O(buckets) regardless of traffic; p50/p99 come from the bucket counts

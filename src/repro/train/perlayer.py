@@ -44,26 +44,15 @@ boundary cotangent is the only thing carried through the layers), and
 the head's embed cotangent is recomputed by a dedicated embed-only vjp
 at the embed step of each pass — one extra head recompute instead of
 holding a V × d f32 cotangent across every layer.
-
-With ``layer_timing`` (an ``obs.metrics.Registry``), the update sweep
-stamps a host clock between layer updates via ordered
-``jax.experimental.io_callback`` — per-layer update wall time lands in
-the ``train.perlayer.layer_update_ms`` histogram (n_layers observations
-per step; zero overhead when disabled).
 """
 from __future__ import annotations
 
-import time
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import io_callback
 
 from repro.configs.base import ModelConfig
 from repro.models.common import remat_wrap
 from repro.models.registry import ModelApi
-from repro.obs import metrics as obs_metrics
 from repro.optim.optimizers import Optimizer
 from repro.train.step import cross_entropy
 
@@ -86,9 +75,7 @@ def make_perlayer_train_step(cfg: ModelConfig, api: ModelApi,
                              optimizer: Optimizer, *, remat: str = "none",
                              grad_accum: int = 1, aux_coef: float = 0.01,
                              fused_opt: bool | None = None,
-                             grad_specs=None,
-                             layer_timing: Optional[
-                                 obs_metrics.Registry] = None):
+                             grad_specs=None):
     """Returns train_step(params, opt_state, consts, batch) ->
     (params, opt_state, metrics) with per-layer in-sweep updates.
 
@@ -96,11 +83,6 @@ def make_perlayer_train_step(cfg: ModelConfig, api: ModelApi,
     ``optimizer.update_slice_fused`` (the Pallas adam8bit kernel) when the
     optimizer provides it; default follows the model's exec mode
     (``cfg.param.exec_mode == "fused"``).
-
-    ``layer_timing`` (a registry, or None = off) turns on per-layer update
-    timing: the update sweep hops to host between layer updates
-    (ordered ``io_callback``) and records the elapsed wall time per layer
-    into ``train.perlayer.layer_update_ms``.
 
     ``grad_accum > 1`` runs the IN-SWEEP microbatch accumulator: the batch
     splits into microbatches, the forward saves boundaries per microbatch
@@ -155,22 +137,6 @@ def make_perlayer_train_step(cfg: ModelConfig, api: ModelApi,
     def pin_full(g, tree_path):
         s = _spec_of(tree_path)
         return constrain(g, *s) if s is not None else g
-
-    # -- optional per-layer update timing (host hop via io_callback) ------
-    if layer_timing is not None:
-        _h_layer = layer_timing.histogram(
-            "train.perlayer.layer_update_ms",
-            buckets=obs_metrics.ms_buckets(),
-            help="wall time between consecutive in-sweep layer updates")
-        _t_prev = {"ns": 0}
-
-        def _stamp_start():
-            _t_prev["ns"] = time.perf_counter_ns()
-
-        def _stamp_layer():
-            now = time.perf_counter_ns()
-            _h_layer.observe((now - _t_prev["ns"]) / 1e6)
-            _t_prev["ns"] = now
 
     def head_params_of(params):
         """Only the UNTIED head leaves — the tied embedding enters
@@ -280,10 +246,6 @@ def make_perlayer_train_step(cfg: ModelConfig, api: ModelApi,
                 else:
                     new_p.append(p_leaves[j])
                     res_g.append(g_leaves[j].astype(jnp.float32))
-            if layer_timing is not None:
-                # ordered host hop: stamps when execution reaches this
-                # point in the sweep, so deltas are per-layer update time
-                io_callback(_stamp_layer, None, ordered=True)
             return dx, (tuple(new_p), tuple(new_ls), tuple(res_g))
 
         if norm_pass:
@@ -470,8 +432,6 @@ def make_perlayer_train_step(cfg: ModelConfig, api: ModelApi,
         ctx, stats = optimizer.prepare(opt_state, gnorm)
         state = opt_state
         new_params = dict(params)
-        if layer_timing is not None:
-            io_callback(_stamp_start, None, ordered=True)
 
         hg = head_grads()   # recompute: don't hold head grads across pass 1
         d_head, dh = hg[0], hg[1]

@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis import roofline
 from repro.ckpt.checkpoint import CheckpointCorruptError, CheckpointManager
 from repro.configs.base import TrainConfig
 from repro.core import relora as relora_lib
@@ -155,8 +154,7 @@ class Trainer:
                  rollback_data_skip: int = 1,
                  obs: Optional[obs_metrics.Registry] = None,
                  trace: Optional[obs_trace.Trace] = None,
-                 metrics_out: Optional[str] = None,
-                 layer_timing: bool = False):
+                 metrics_out: Optional[str] = None):
         self.tc = tc
         self.mesh = mesh
         self.log = log_fn
@@ -180,12 +178,11 @@ class Trainer:
 
         # -- observability (repro.obs): own registry per trainer so
         # side-by-side runs (sweeps, tests) never share counters; pass a
-        # shared one to aggregate. Trace defaults disabled = no-op spans.
+        # shared one to aggregate. Spans go to the process recorder unless
+        # a trace is passed (Trace(enabled=False) turns them off).
         self.obs = obs if obs is not None else obs_metrics.Registry()
-        self.trace = trace if trace is not None \
-            else obs_trace.Trace(enabled=False)
+        self.trace = trace if trace is not None else obs_trace.get_trace()
         self.metrics_out = metrics_out
-        self._chips = 1 if mesh is None else int(mesh.devices.size)
         self._c_steps = self.obs.counter("train.steps")
         self._c_tokens = self.obs.counter(
             "train.tokens", help="tokens consumed (global batch x seq)")
@@ -193,11 +190,8 @@ class Trainer:
         self._g_lr = self.obs.gauge("train.lr")
         self._g_gnorm = self.obs.gauge("train.grad_norm")
         self._g_tps = self.obs.gauge(
-            "train.tokens_per_sec", help="tokens / (dispatch + sync) time")
-        self._g_mfu = self.obs.gauge(
-            "train.mfu", help="6ND model-FLOPs utilisation vs chip peak "
-            "(analysis.roofline.train_mfu); unset = not measured (CPU)")
-        self._peaks = roofline.peaks_for(jax.devices()[0])
+            "train.tokens_per_sec",
+            help="tokens / (data + dispatch + sync) time of the step")
         self._h_step = self.obs.histogram(
             "train.step_ms", buckets=obs_metrics.ms_buckets())
         phase_h = self.obs.histogram(
@@ -217,7 +211,6 @@ class Trainer:
         if self.chaos is not None:
             self.chaos.bind(self.obs)
 
-        self._layer_timing = layer_timing
         self._train_step = self._build_train_step(grad_specs=None)
         self._relora_merge = jax.jit(_make_relora_merge(self.cfg)) \
             if self.cfg.param.mode == "relora" else None
@@ -240,8 +233,7 @@ class Trainer:
                 self.cfg, self.api, self.optimizer,
                 remat=tc.sharding.remat,
                 grad_accum=tc.sharding.grad_accum,
-                grad_specs=grad_specs,
-                layer_timing=self.obs if self._layer_timing else None))
+                grad_specs=grad_specs))
         if tc.sharding.update_mode != "global":
             raise ValueError(f"unknown update_mode "
                              f"{tc.sharding.update_mode!r}: expected "
@@ -405,39 +397,58 @@ class Trainer:
             state = self.restore_or_init()
         state = self._place(state)
         self._install_signal_handlers()
-        tokens_per_step = tc.global_batch * tc.seq_len
         while state.step < total:
-            if self.chaos is not None:
-                # injected kills / checkpoint corruption (may raise
-                # ChaosKill — a SystemExit(43) the relaunch recovers from)
-                self.chaos.train_hook(state.step, ckpt_dir=self.tc.ckpt_dir)
-            if self.fault_hook:
-                self.fault_hook(state.step)  # test hook: may raise/kill
             with self.trace.span("train.step", cat="train",
-                                 step=state.step + 1):
-                t0 = time.perf_counter()
-                with self.trace.span("train.data", cat="train"):
-                    batch_np = self._next_valid_batch(state.step)
-                    if self.chaos is not None and self.chaos.wants_poison:
-                        # constant pytree: the key rides along EVERY step
-                        # (value 1.0 off-fault), so chaos costs one compile
-                        batch_np = dict(batch_np)
-                        batch_np["chaos_scale"] = np.full(
-                            (batch_np["tokens"].shape[0],),
-                            self.chaos.poison_scale(state.step), np.float32)
-                    batch = {k: jax.numpy.asarray(v)
-                             for k, v in batch_np.items()}
-                t1 = time.perf_counter()
-                with self._mesh_ctx(), \
-                        self.trace.span("train.dispatch", cat="train"):
-                    params, opt_state, metrics = self._train_step(
-                        state.params, state.opt_state, state.consts, batch)
-                t2 = time.perf_counter()
-                if self.chaos is not None:
-                    self.chaos.straggle(state.step)  # inside the dt window
-                with self.trace.span("train.sync", cat="train"):
-                    jax.block_until_ready(metrics["loss"])
-                t3 = time.perf_counter()
+                                 step_num=state.step + 1):
+                state = self._step(state, total)
+        self.save(state, background=False)
+        self.ckpt.wait()
+        return state
+
+    def _step(self, state: TrainerState, total: int) -> TrainerState:
+        """One iteration of :meth:`run`: data, dispatch, sync, then the
+        read-back of the step's metrics, logging, and the resilience and
+        checkpoint decisions. Returns the state the loop goes on from."""
+        tc = self.tc
+        tokens_per_step = tc.global_batch * tc.seq_len
+        if self.chaos is not None:
+            # injected kills / checkpoint corruption (may raise
+            # ChaosKill — a SystemExit(43) the relaunch recovers from)
+            self.chaos.train_hook(state.step, ckpt_dir=self.tc.ckpt_dir)
+        if self.fault_hook:
+            self.fault_hook(state.step)  # test hook: may raise/kill
+        t0 = time.perf_counter()
+        with self.trace.span("train.data", cat="train"):
+            batch_np = self._next_valid_batch(state.step)
+            if self.chaos is not None and self.chaos.wants_poison:
+                # constant pytree: the key rides along EVERY step
+                # (value 1.0 off-fault), so chaos costs one compile
+                batch_np = dict(batch_np)
+                batch_np["chaos_scale"] = np.full(
+                    (batch_np["tokens"].shape[0],),
+                    self.chaos.poison_scale(state.step), np.float32)
+            batch = {k: jax.numpy.asarray(v) for k, v in batch_np.items()}
+        t1 = time.perf_counter()
+        with self._mesh_ctx(), \
+                self.trace.span("train.dispatch", cat="train"):
+            params, opt_state, metrics = self._train_step(
+                state.params, state.opt_state, state.consts, batch)
+        t2 = time.perf_counter()
+        if self.chaos is not None:
+            self.chaos.straggle(state.step)  # inside the dt window
+        with self.trace.span("train.sync", cat="train"):
+            jax.block_until_ready(metrics["loss"])
+        t3 = time.perf_counter()
+        state = TrainerState(params, opt_state, state.consts, state.step + 1)
+        if self._relora_merge is not None and \
+                state.step % self.cfg.param.relora_period == 0:
+            key = jax.random.fold_in(jax.random.PRNGKey(self.tc.seed),
+                                     state.step)
+            params, opt_state = self._relora_merge(
+                state.params, state.opt_state, key)
+            state = TrainerState(params, opt_state, state.consts, state.step)
+            self.log(f"[trainer] ReLoRA merge+restart at {state.step}")
+        with self.trace.span("train.readback", cat="train"):
             # dt keeps its historical meaning: dispatch + sync (excludes
             # host-side data work) — the watchdog/history currency
             dt = t3 - t1
@@ -447,17 +458,6 @@ class Trainer:
             self._h_step.observe(dt * 1e3)
             self._c_steps.inc()
             self._c_tokens.inc(tokens_per_step)
-            state = TrainerState(params, opt_state, state.consts,
-                                 state.step + 1)
-            if self._relora_merge is not None and \
-                    state.step % self.cfg.param.relora_period == 0:
-                key = jax.random.fold_in(jax.random.PRNGKey(self.tc.seed),
-                                         state.step)
-                params, opt_state = self._relora_merge(
-                    state.params, state.opt_state, key)
-                state = TrainerState(params, opt_state, state.consts,
-                                     state.step)
-                self.log(f"[trainer] ReLoRA merge+restart at {state.step}")
             slow = self.watchdog.observe(state.step, dt)
             row = {k: float(v) for k, v in metrics.items()}
             row.update(step=state.step, dt=dt)
@@ -479,10 +479,7 @@ class Trainer:
                 self._g_lr.set(row["lr"])
             if "grad_norm" in row:
                 self._g_gnorm.set(row["grad_norm"])
-            self._g_tps.set(tokens_per_step / dt if dt > 0 else 0.0)
-            if self._peaks is not None:
-                self._g_mfu.set(roofline.train_mfu(
-                    self.cfg, tokens_per_step, dt, self._peaks, self._chips))
+            self._g_tps.set(tokens_per_step / (t3 - t0))
             if state.step % tc.log_every == 0 or state.step == total:
                 # log line reads back from the registry — the gauges ARE
                 # the trainer's reporting surface, not a side channel
@@ -490,22 +487,17 @@ class Trainer:
                          f"loss={self._g_loss.value:.4f} "
                          f"lr={self._g_lr.value or 0:.2e} {dt*1e3:.0f}ms "
                          f"{self._g_tps.value:.0f}tok/s"
-                         + (f" mfu={self._g_mfu.value:.4f}"
-                            if self._g_mfu.value is not None else "")
                          + (" STRAGGLER" if slow else ""))
                 if self.metrics_out:
                     self.obs.write_jsonl(self.metrics_out,
                                          extra={"step": state.step})
-            if skipped and self._skip_streak >= self.max_skips:
-                state = self._rollback("non-finite loss/grads")
-                continue
-            if self._preempted:
-                self.log("[trainer] preemption signal: checkpoint + exit 42")
-                self.save(state, background=False)
-                self.ckpt.wait()
-                sys.exit(42)
-            if tc.ckpt_every and state.step % tc.ckpt_every == 0:
-                self.save(state)
-        self.save(state, background=False)
-        self.ckpt.wait()
+        if skipped and self._skip_streak >= self.max_skips:
+            return self._rollback("non-finite loss/grads")
+        if self._preempted:
+            self.log("[trainer] preemption signal: checkpoint + exit 42")
+            self.save(state, background=False)
+            self.ckpt.wait()
+            sys.exit(42)
+        if tc.ckpt_every and state.step % tc.ckpt_every == 0:
+            self.save(state)
         return state

@@ -389,8 +389,9 @@ def test_trainer_gauges_spans_and_jsonl(tmp_path):
         t.metrics_history[-1]["loss"])
     assert snap["train.lr"]["value"] == pytest.approx(
         t.metrics_history[-1]["lr"])
-    # no published peaks for the CPU: MFU stays unset ("not measured")
-    assert snap["train.mfu"]["value"] is None
+    # the dense 6ND MFU gauge is gone: the benchmark's train_mfu counts
+    # the factored model's own FLOPs from the device trace
+    assert "train.mfu" not in snap
     assert snap["train.tokens_per_sec"]["value"] > 0
     assert snap["train.step_ms"]["count"] == 3
     for phase in ("data", "dispatch", "sync"):

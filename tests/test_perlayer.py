@@ -127,41 +127,6 @@ def test_perlayer_tied_embeddings_fold_head_cotangent(opt_name):
     np.testing.assert_allclose(gn_p, gn_g, rtol=2e-5, atol=0)
 
 
-def test_perlayer_layer_timing_histogram():
-    """With a layer_timing registry the update sweep records one
-    observation per layer per step via ordered io_callback — and the
-    timing hop must not perturb the math (loss parity vs untimed)."""
-    from repro.obs import metrics as obs_metrics
-
-    steps = 2
-    cfg = _smoke_cfg("dense")
-    api = registry.get_api(cfg)
-    opt = optimizers.make(OptimizerConfig(name="adamw", lr=1e-3,
-                                          warmup_steps=2, total_steps=steps))
-    reg = obs_metrics.Registry()
-    runs = {}
-    for label, timing in (("untimed", None), ("timed", reg)):
-        params, consts = api.init(cfg, jax.random.PRNGKey(42), seed=42)
-        opt_state = opt.init(params)
-        fn = jax.jit(perlayer.make_perlayer_train_step(
-            cfg, api, opt, layer_timing=timing))
-        data = SyntheticC4(cfg.vocab_size, 32, 4, seed=0)
-        losses = []
-        for _ in range(steps):
-            batch = {k: jnp.asarray(v)
-                     for k, v in data.next_batch().items()}
-            params, opt_state, metrics = fn(params, opt_state, consts,
-                                            batch)
-            losses.append(float(metrics["loss"]))
-        runs[label] = losses
-
-    assert runs["timed"] == runs["untimed"]
-    h = reg.get("train.perlayer.layer_update_ms")
-    jax.effects_barrier()  # drain any in-flight ordered callbacks
-    assert h.count == steps * cfg.n_layers, (h.count, cfg.n_layers)
-    assert h.sum >= 0
-
-
 def test_perlayer_galore_runs_and_tracks_global():
     steps = 4
     cfg = _smoke_cfg("dense")

@@ -156,17 +156,6 @@ def test_peaks_unknown_accelerator_raises():
         roofline.peaks_for(_Dev("tpu", "TPU v99"))
 
 
-def test_train_mfu_against_peaks():
-    cfg = registry.get_config("yi_34b")
-    peaks = roofline.PEAKS[roofline.V5E]
-    tokens = 4096
-    flops = roofline.model_flops(cfg, tokens, "train")
-    # a step that delivers exactly half of two chips' peak
-    dt = flops / (0.5 * 2 * peaks.flops)
-    assert roofline.train_mfu(cfg, tokens, dt, peaks, chips=2) == \
-        pytest.approx(0.5)
-
-
 def test_model_flops_moe_uses_active_params():
     dense = registry.get_config("yi_34b")
     moe = registry.get_config("qwen3_moe_235b")
