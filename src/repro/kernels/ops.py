@@ -116,36 +116,55 @@ def _pad2(x, mult_r, mult_c):
 # Forward / backward wrappers
 # ---------------------------------------------------------------------------
 
+def _row_block(kernel: str, m: int, tiles, vmem, row_cap):
+    """The kernel's row block for a call of ``m`` rows (the row rule,
+    ``sl_matmul.row_blocks``), recorded as the process recorder's
+    ``sl.row_blocks`` instant when the call is traced."""
+    rows, n_blocks = sl_kernel.row_blocks(m, *vmem, cap=row_cap)
+    obs_trace.get_trace().instant(
+        "sl.row_blocks", cat="kernel", kernel=kernel, m=m, rows=rows,
+        row_blocks=n_blocks, tiles=tiles[0] * tiles[1])
+    return rows
+
+
 def sl_matmul(x, B, A, v_t, rows_t, cols_t, scale: float, *,
-              bm: int = 128, interpret: bool | None = None):
-    """y = x @ (scale·B·A ⊕ V); arbitrary (unpadded) logical shapes."""
+              row_cap: int | None = None, interpret: bool | None = None):
+    """y = x @ (scale·B·A ⊕ V); arbitrary (unpadded) logical shapes.
+    ``row_cap`` overrides the VMEM cap on the rows of one row block."""
     interp = interpret_mode(interpret)
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = A.shape[-1]
+    m = int(np.prod(lead)) if lead else 1
+    bm = _row_block("sl_matmul", m, v_t.shape, sl_kernel.vmem_bytes(
+        128, 128, B.shape[-1], v_t.shape[-1], x.dtype.itemsize), row_cap)
     xf = _pad2(x.reshape(-1, k), bm, 128)
     Bp = _pad2(B, 128, 1)
     Ap = _pad2(A, 1, 128)
     y = sl_kernel.sl_matmul(xf, Bp, Ap, v_t, rows_t, cols_t, scale=scale,
                             bm=bm, interpret=interp)
-    m = int(np.prod(lead)) if lead else 1
     return y[:m, :n].reshape(*lead, n)
 
 
-def sddmm(x, dy, rows_t, cols_t, *, bm: int = 128,
+def sddmm(x, dy, rows_t, cols_t, *, row_cap: int | None = None,
           interpret: bool | None = None):
     """dv tiles for support (rows_t, cols_t); x (..., K), dy (..., N).
 
     Output is f32: the kernel forms each G tile with
     ``preferred_element_type=f32`` and accumulates over the token grid in
-    an f32 output block, so bf16 inputs never round dv through bf16 (same
+    an f32 VMEM scratch, so bf16 inputs never round dv through bf16 (same
     accumulation contract as the sparse-decode fix). Upstream often hands
     f32 cotangents against bf16 activations — align dy to x's dtype here
-    (the MXU dot needs matching operand dtypes; accumulation stays f32)."""
+    (the MXU dot needs matching operand dtypes; accumulation stays f32).
+    ``row_cap`` overrides the VMEM cap on the rows of one row block."""
     interp = interpret_mode(interpret)
     k = x.shape[-1]
     n = dy.shape[-1]
-    xf = _pad2(x.reshape(-1, k), bm, 128)
+    xf = x.reshape(-1, k)
+    bm = _row_block("sddmm", xf.shape[0], rows_t.shape,
+                    sddmm_kernel.vmem_bytes(128, 128, rows_t.shape[-1],
+                                            x.dtype.itemsize), row_cap)
+    xf = _pad2(xf, bm, 128)
     dyf = _pad2(dy.reshape(-1, n).astype(x.dtype), bm, 128)
     return sddmm_kernel.sddmm(xf, dyf, rows_t, cols_t, bm=bm,
                               interpret=interp)

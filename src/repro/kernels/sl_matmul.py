@@ -19,7 +19,19 @@ Support layout: ``support.tile_layout`` buckets the fixed support by
 tight concentration). Padding slots carry v = 0 so they contribute nothing.
 
 Grid: (M/bm, N/bn, K/bk), k innermost; the f32 output block is revisited
-across k and used as the accumulator (standard Pallas matmul pattern).
+across k and used as the accumulator (standard Pallas matmul pattern), and
+A's (r, bn) block is constant across k, so it is fetched once per column
+tile. Building a tile costs far more MXU work (B_k·A_j over r, the f32
+one-hot product) than spending it on 128 rows, so the row block ``bm``
+covers every row of the call, up to a cap (:func:`row_blocks`): each tile
+is built once per row block, ⌈M/cap⌉ times a call, and at the training
+step's M every tile is built once.
+
+Row rule (:func:`row_blocks`, shared with ``sddmm``): the cap is the most
+rows, a multiple of 128, whose blocks fit :data:`VMEM_BUDGET` by the
+kernel's own estimate (:func:`vmem_bytes`). M, rounded up to 128, is split
+into the fewest row blocks under the cap, of equal size, padded up to a
+multiple of 128; M ≤ 128 keeps one 128-row block.
 """
 from __future__ import annotations
 
@@ -28,6 +40,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+#: VMEM the row rule fits a call's blocks and temporaries into: half of the
+#: v5e's 16 MiB default scoped VMEM, the rest left to the compiler
+VMEM_BUDGET = 8 * 2**20
+#: rows are counted and padded in multiples of this
+ROW_ALIGN = 128
+
+
+def _round_up(a: int, b: int) -> int:
+    return -(-a // b) * b
+
+
+def row_blocks(m: int, fixed: int, per_row: int,
+               cap: int | None = None) -> tuple[int, int]:
+    """(rows per block, row blocks) for a call of ``m`` token rows whose
+    blocks take ``fixed + rows·per_row`` bytes of VMEM. The cap is the
+    most rows that fit :data:`VMEM_BUDGET`, or ``cap`` where given, rounded
+    down to a multiple of 128 and at least 128."""
+    if cap is None:
+        cap = (VMEM_BUDGET - fixed) // per_row
+    cap = max(ROW_ALIGN, cap // ROW_ALIGN * ROW_ALIGN)
+    m_al = _round_up(max(m, 1), ROW_ALIGN)
+    n_blocks = -(-m_al // cap)
+    return _round_up(-(-m_al // n_blocks), ROW_ALIGN), n_blocks
+
+
+def vmem_bytes(bk: int, bn: int, r: int, e: int,
+               itemsize: int) -> tuple[int, int]:
+    """(fixed, per row) VMEM bytes of one call's blocks, double-buffered as
+    Pallas pipelines them, and of its temporaries: B's (bk, r) and A's
+    (r, bn) blocks, the three (1, E) support rows (padded to 8 sublanes),
+    the two (·, E) f32 one-hots and the f32 tile with its cast; per row,
+    x's block, the f32 output block and the x·w product."""
+    r_al = _round_up(r, 128)
+    fixed = (2 * (bk + bn) * r_al * itemsize + 3 * 2 * 8 * e * 4
+             + (bk + bn) * e * 4 + bk * bn * (4 + itemsize))
+    return fixed, 2 * bk * itemsize + 3 * bn * 4
 
 
 def sparse_tile(v, rows, cols, bk: int, bn: int):
@@ -85,7 +135,8 @@ def sl_matmul(x, B, A, v_t, rows_t, cols_t, *, scale: float,
 
     v_t/rows_t/cols_t: (K/bk, N/bn, E) tile-CSR arrays from
     ``ops.prepare_tiles`` (E = padded per-tile capacity, pad v = 0).
-    Shapes must be pre-padded to tile multiples (ops.py handles this).
+    Shapes must be pre-padded to tile multiples and M to the row block
+    ``bm`` (ops.py chooses ``bm`` by :func:`row_blocks` and pads).
     """
     m, k = x.shape
     n = A.shape[1]

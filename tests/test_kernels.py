@@ -62,6 +62,52 @@ def test_sddmm_matches_oracle(d_in, d_out, r, m, delta):
                                rtol=1e-3)
 
 
+# (m, row cap, row blocks): the default rule puts 640 rows in one block; a
+# cap of 384 splits 1000 rows into three blocks of 384, the last padded
+ROW_BLOCK_CASES = [(640, None, 1), (1000, 384, 3)]
+
+
+def _recorded_row_blocks(kernel):
+    """(rows, row_blocks) of the kernel's last ``sl.row_blocks`` instant."""
+    import repro.obs
+    args = [e["args"] for e in repro.obs.get_trace().events
+            if e["name"] == "sl.row_blocks"
+            and e["args"]["kernel"] == kernel][-1]
+    return args["rows"], args["row_blocks"]
+
+
+@pytest.mark.parametrize("m,row_cap,n_blocks", ROW_BLOCK_CASES)
+def test_sl_matmul_row_blocks_match_oracle(m, row_cap, n_blocks):
+    x, B, A, rows, cols, v, (v_t, r_t, c_t, perm) = _mk(
+        256, 384, 32, m, 0.03, jnp.float32, seed=5)
+    y = ops.sl_matmul(x, B, A, v_t, r_t, c_t, 0.25, row_cap=row_cap)
+    assert _recorded_row_blocks("sl_matmul")[1] == n_blocks
+    y_ref = ref.sl_matmul_ref(x, B, A, rows, cols, v, 0.25)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               atol=1e-4, rtol=1e-4)
+    # the same tiles spent on 128-row blocks: a row block changes no
+    # product's reduction order
+    y128 = ops.sl_matmul(x, B, A, v_t, r_t, c_t, 0.25, row_cap=128)
+    assert _recorded_row_blocks("sl_matmul") == (128, -(-m // 128))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y128), rtol=1e-6)
+
+
+@pytest.mark.parametrize("m,row_cap,n_blocks", ROW_BLOCK_CASES)
+def test_sddmm_row_blocks_match_oracle(m, row_cap, n_blocks):
+    x, B, A, rows, cols, v, (v_t, r_t, c_t, perm) = _mk(
+        256, 384, 32, m, 0.03, jnp.float32, seed=6)
+    dy = jnp.asarray(np.random.default_rng(7).standard_normal((m, 384)),
+                     jnp.float32)
+    dv_t = ops.sddmm(x, dy, r_t, c_t, row_cap=row_cap)
+    assert _recorded_row_blocks("sddmm")[1] == n_blocks
+    perm_np = np.asarray(perm).reshape(-1)
+    mask = perm_np >= 0
+    recon = np.zeros(rows.shape[0], np.float32)
+    recon[perm_np[mask]] = np.asarray(dv_t).reshape(-1)[mask]
+    np.testing.assert_allclose(recon, np.asarray(ref.sddmm_ref(
+        x, dy, rows, cols)), atol=1e-3, rtol=1e-3)
+
+
 def test_fused_vjp_matches_core_autodiff():
     """The pallas custom-VJP linear must produce the same gradients as the
     XLA densify path in core.sltrain (paper eq. 2)."""

@@ -1,7 +1,8 @@
-"""The per-layer readers of the entry and loop layers
+"""The per-layer readers of the entry, loop and kernels layers
 (``benchmarks/chip/metrics/*.py``) on a synthesized span list: compiles
-before, inside and after the window, tile tables before it, and the host
-gap between one step's sync and the next step's dispatch."""
+before, inside and after the window, tile tables before it, the host
+gap between one step's sync and the next step's dispatch, and the SL
+kernels' row blocks per call."""
 import importlib.util
 import os
 
@@ -12,7 +13,8 @@ import repro.obs
 METRICS = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
                        "chip", "metrics")
 READERS = ("setup_compile_s.train", "setup_tile_tables_s.train",
-           "window_compiles.train", "trainer_host_gap_ms.train")
+           "window_compiles.train", "trainer_host_gap_ms.train",
+           "sl_row_blocks.train")
 
 
 def reader(name):
@@ -38,6 +40,14 @@ def X(name, ts, dur, sid=None, parent=None, **args):
     if args:
         ev["args"] = args
     return ev
+
+
+def row_blocks(ts, kernel, m, rows, n_blocks, tiles=40 * 216):
+    """The ``sl.row_blocks`` instant ``kernels/ops`` records per traced
+    SL kernel call."""
+    return {"name": "sl.row_blocks", "ph": "i", "ts": float(ts),
+            "args": {"kernel": kernel, "m": m, "rows": rows,
+                     "row_blocks": n_blocks, "tiles": tiles}}
 
 
 def steps(first_id, t0, n, period=1000.0):
@@ -114,6 +124,29 @@ def test_trainer_host_gap_ms_bounds_by_the_window(events):
         dict(CTX, steps=1)) is None
 
 
+def test_sl_row_blocks_reads_one_when_each_call_has_one_block(events):
+    # the step traced in set-up: forward, dx and dv of one linear
+    events += [row_blocks(1_100_000, "sl_matmul", 2048, 2048, 1),
+               row_blocks(1_100_010, "sddmm", 2048, 2048, 1),
+               row_blocks(1_100_020, "sl_matmul", 2048, 2048, 1)]
+    assert reader("sl_row_blocks.train").read(CTX) == 1.0
+
+
+def test_sl_row_blocks_reads_the_mean_over_calls_above_the_cap(events):
+    # 16 blocks of 128 rows (the old fixed block) and 3 above a cap;
+    # an instant after the window (the harness's later compile) is not read
+    events += [row_blocks(1_100_000, "sl_matmul", 2048, 128, 16),
+               row_blocks(1_100_010, "sddmm", 1000, 384, 3),
+               row_blocks(1_100_020, "sl_matmul", 64, 128, 1),
+               row_blocks(9_000_000, "sl_matmul", 2048, 128, 16)]
+    assert reader("sl_row_blocks.train").read(CTX) == pytest.approx(20 / 3)
+
+
+def test_sl_row_blocks_reads_nothing_without_the_instants(events):
+    # the window's spans are there; the parent's kernels record no instant
+    assert reader("sl_row_blocks.train").read(CTX) is None
+
+
 @pytest.mark.parametrize("name", READERS)
 def test_readers_read_nothing_without_the_programs_spans(name, monkeypatch):
     monkeypatch.setattr(repro.obs, "get_trace", lambda: _Recorder([]))
@@ -145,4 +178,5 @@ def test_traced_tiny_cell_reports_the_new_metrics(tmp_path):
     assert got["setup_compile_s.train"] > 0
     assert got["setup_tile_tables_s.train"] > 0
     assert got["trainer_host_gap_ms.train"] > 0
+    assert got["sl_row_blocks.train"] == 1.0
     assert res["correct"]

@@ -1,5 +1,7 @@
 """Compile every Pallas kernel of the main path for a described TPU v5e,
-at the paper's LLaMA-1B widths, from this CPU process.
+at the paper's LLaMA-1B widths, from this CPU process; and the SL kernels
+at the chip benchmark's Qwen2.5-32B widths, where one row block holds the
+training step's 2048 tokens.
 
 Interpret-mode tests cannot see what the chip's compiler refuses: block
 shapes the TPU tiling does not accept, sublane packing, fast-memory use.
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.obs
 from repro.configs.llama_paper import LLAMA_1B
 from repro.core import support
 from repro.kernels import ops
@@ -23,6 +26,8 @@ D, FF = LLAMA_1B.d_model, LLAMA_1B.d_ff
 RANK, DELTA = LLAMA_1B.param.rank, LLAMA_1B.param.delta
 HEADS, HD = LLAMA_1B.n_heads, LLAMA_1B.d_model // LLAMA_1B.n_heads
 BATCH, SEQ = 8, 256                 # the training step of chip_smoke.py
+# the chip benchmark's cell: Qwen2.5-32B widths, seq 2048 x batch 1
+Q_D, Q_FF, Q_RANK, Q_DELTA, Q_SEQ = 5120, 27648, 1280, 0.03, 2048
 SLOTS, BLOCK_LEN, MAX_LEN, CHUNK = 4, 16, 128, 32
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -46,9 +51,33 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
-def _tiles(d_in, d_out, dtype=I32):
+def _tiles(d_in, d_out, dtype=I32, delta=DELTA):
     nkt, nnt = -(-d_in // 128), -(-d_out // 128)
-    return (nkt, nnt, support.tile_cap(d_in, d_out, DELTA)), dtype
+    return (nkt, nnt, support.tile_cap(d_in, d_out, delta)), dtype
+
+
+def _qwen_cases():
+    """The SL kernels of the benchmark cell's MLP up projection (d → d_ff):
+    forward, dx on the transposed factors and swapped tile arrays (K d_ff
+    → N d), and dv."""
+    up = _tiles(Q_D, Q_FF, delta=Q_DELTA)
+    nkt, nnt, cap = up[0]
+    up_t = ((nnt, nkt, cap), I32)
+    return {
+        "sl_matmul.qwen2.5-32b.fwd": (
+            lambda x, B, A, v, r, c: ops.sl_matmul(
+                x, B, A, v, r, c, 0.5, interpret=False),
+            [((1, Q_SEQ, Q_D), BF16), ((Q_D, Q_RANK), BF16),
+             ((Q_RANK, Q_FF), BF16), (up[0], F32), up, up]),
+        "sl_matmul.qwen2.5-32b.dx": (
+            lambda dy, At, Bt, v, r, c: ops.sl_matmul(
+                dy, At, Bt, v, r, c, 0.5, interpret=False),
+            [((1, Q_SEQ, Q_FF), BF16), ((Q_FF, Q_RANK), BF16),
+             ((Q_RANK, Q_D), BF16), (up_t[0], F32), up_t, up_t]),
+        "sddmm.qwen2.5-32b.dv": (
+            lambda x, dy, r, c: ops.sddmm(x, dy, r, c, interpret=False),
+            [((1, Q_SEQ, Q_D), BF16), ((1, Q_SEQ, Q_FF), BF16), up, up]),
+    }
 
 
 def _cases():
@@ -96,6 +125,7 @@ def _cases():
             lambda q, k, v, t, o: ops.paged_prefill_attention(
                 q, k, v, t, o, scale=HD ** -0.5, interpret=False),
             [((SLOTS, CHUNK, HEADS, HD), BF16), pool, pool, table, slots]),
+        **_qwen_cases(),
     }
 
 
@@ -109,3 +139,9 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 ** 30, mem
+    if name in _qwen_cases():
+        # the whole step's 2048 tokens in one row block: each weight tile
+        # is built (or gathered) once per call
+        last = [e for e in repro.obs.get_trace().events
+                if e["name"] == "sl.row_blocks"][-1]["args"]
+        assert (last["rows"], last["row_blocks"]) == (Q_SEQ, 1), last
