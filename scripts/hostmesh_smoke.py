@@ -176,7 +176,7 @@ def smoke_fused_dist():
     dy = jnp.asarray(rng.standard_normal((2, 8, d_out)), jnp.float32)
 
     # the island must actually engage under the TP mesh (geometry divides)
-    v_t = ops._gather_tiles(params["v"], consts["perm"])
+    v_t = ops._to_tiles(params["v"], consts["perm"])
     with mesh:
         out = ops._fused_grads_dist(x, params["B"], params["A"], v_t,
                                     consts["rows_t"], consts["cols_t"],

@@ -23,7 +23,10 @@ Four execution modes (DESIGN §3; the full matrix lives in
   (``init_params(..., exec_mode="fused")``): int32 {rows_t, cols_t, perm}
   with a DETERMINISTIC per-tile capacity (``support.tile_cap``) so the
   no-alloc dry-run twin and per-layer stacking agree; the trainable ``v``
-  stays flat and is gathered/scattered through ``perm`` inside the jit.
+  stays flat and moves to tile order (and dv back) inside the jit by
+  sorts keyed on ``perm``: on the TPU a sort moves the whole array in
+  regular passes, where a gather or scatter-add addresses each element
+  alone.
 * ``quant`` — serve-only post-training path (repro.quant): the sparse
   values run as int8 tile-CSR codes against per-output-channel f32
   scales through the quantized Pallas decode kernel; B/A stay bf16 with

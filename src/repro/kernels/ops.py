@@ -89,13 +89,15 @@ def prepare_tile_consts(rows: np.ndarray, cols: np.ndarray, d_in: int,
 
     Unlike :func:`prepare_tiles` this bakes NO values: the trainable ``v``
     stays flat in the param tree (optimizer state / checkpoints / the
-    sparse decode path stay layout-independent) and is gathered into tile
-    order through ``perm`` inside the jit'd forward (``sl_linear``). The
-    capacity ``pad`` must be the deterministic ``support.tile_cap`` bound
-    so abstract dry-run shapes match concrete init and per-layer consts
-    stack; raises ``ValueError`` when the sampled support exceeds it
-    (callers re-sample on host). The host build and the transfer are the
-    process recorder's ``sl.tile_tables`` span."""
+    sparse decode path stay layout-independent) and moves into tile order
+    inside the jit'd forward (``sl_linear``) by sorts keyed on ``perm``,
+    which XLA's TPU sort runs in whole-array passes rather than one
+    addressed element at a time, as a gather would. The capacity ``pad``
+    must be the deterministic ``support.tile_cap`` bound so abstract
+    dry-run shapes match concrete init and per-layer consts stack;
+    raises ``ValueError`` when the sampled support exceeds it (callers
+    re-sample on host). The host build and the transfer are the process
+    recorder's ``sl.tile_tables`` span."""
     with obs_trace.get_trace().span("sl.tile_tables", cat="init",
                                     d_in=d_in, d_out=d_out):
         rt, ct, perm = _tile_index_arrays(rows, cols, d_in, d_out, tile_r,
@@ -327,31 +329,89 @@ sl_linear_fused.defvjp(_fused_fwd, _fused_bwd)
 # Flat-v fused linear (exec_mode="fused" training path)
 # ---------------------------------------------------------------------------
 
-def _gather_tiles(v, perm):
-    """Flat trainable v → f32 tile values through the layout permutation.
-    Padding slots (perm == -1) contribute exactly 0 through the kernel."""
-    vf = v.reshape(-1).astype(jnp.float32)
-    safe = jnp.clip(perm, 0, vf.shape[0] - 1)
-    return jnp.where(perm >= 0, vf[safe], 0.0)
+def _permute_blocks(v, perm):
+    """(blocks, sources per block): the permutation never crosses a row
+    tile, so a row-balanced v (d_in, k), whose row tile i holds flat
+    entries [i·per, (i+1)·per), sorts each row tile as one row of a
+    batch; a COO v is one block."""
+    if v.ndim != 2:
+        return 1, v.size
+    return perm.shape[0], min(support_lib.TILE, v.shape[0]) * v.shape[1]
+
+
+def _slot_keys(perm, blocks: int, per: int):
+    """The source key of every tile slot, (blocks, slots per block): the
+    slot's index into its block of flat v (``perm`` less the block's
+    start) where it holds an entry, ``per + slot`` on a padding slot.
+    ``perm`` is a bijection between the valid slots and v's entries
+    (``support.tile_layout``), so the keys of a block are unique and a
+    sort by them applies the permutation exactly, pads last."""
+    p = perm.reshape(blocks, -1)
+    start = jax.lax.broadcasted_iota(p.dtype, p.shape, 0) * per
+    pad = per + jax.lax.broadcasted_iota(p.dtype, p.shape, 1)
+    return jnp.where(p >= 0, p - start, pad)
+
+
+def _sort_by(key, payload):
+    return jax.lax.sort((key, payload), dimension=1, num_keys=1,
+                        is_stable=False)[1]
+
+
+def _permute_instant(pass_: str, perm, nnz: int):
+    """The process recorder's ``sl.permute`` instant, recorded each time a
+    pass of :func:`sl_linear` moves v or dv between its layouts."""
+    obs_trace.get_trace().instant(
+        "sl.permute", cat="kernel", how="sort", slots=perm.size, nnz=nnz,
+        **{"pass": pass_})
+
+
+def _to_tiles(v, perm):
+    """Flat trainable v → f32 tile values, 0 on the padding slots. Two
+    sorts by key, per block: the slot indices sorted by their source key
+    give each entry's slot (pads after), and v, padded with zeros to the
+    slot count, sorted by those slots lands in tile order. XLA's TPU sort
+    moves whole arrays in regular passes, where a gather through ``perm``
+    addresses every element alone."""
+    _permute_instant("fwd", perm, v.size)
+    blocks, per = _permute_blocks(v, perm)
+    key = _slot_keys(perm, blocks, per)
+    dest = _sort_by(key, jax.lax.broadcasted_iota(key.dtype, key.shape, 1))
+    # row-balanced v padded to whole row tiles; the pad rows own no slot
+    src = jnp.pad(v.reshape(-1), (0, blocks * per - v.size))
+    src = jnp.pad(src.reshape(blocks, per),
+                  ((0, 0), (0, key.shape[1] - per)))
+    return _sort_by(dest, src).astype(jnp.float32).reshape(perm.shape)
+
+
+def _to_flat(dv_t, perm, v):
+    """f32 tile grads → dv in v's flat layout: per block, one sort of the
+    grads by their slot's source key, whose first ``per`` are the block's
+    dv in flat order; the pad slots' values sort after them and are
+    dropped. It replaces a scatter-add through ``perm`` and the index
+    sort XLA runs for it, and is exact: every entry of v owns one slot."""
+    _permute_instant("dv", perm, v.size)
+    blocks, per = _permute_blocks(v, perm)
+    dv = _sort_by(_slot_keys(perm, blocks, per), dv_t.reshape(blocks, -1))
+    return dv[:, :per].reshape(-1)[:v.size].reshape(v.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def sl_linear(x, B, A, v, rows_t, cols_t, perm, scale):
     """y = x @ (scale·B·A ⊕ V) with the trainable ``v`` in its FLAT layout
     (row-balanced (d_in, k) or COO (nnz,)) — the param-tree leaf the
-    optimizer/checkpoints see. The tile gather (fwd) and scatter (bwd)
-    happen inside the jit, so only the layout-independent flat v is ever
+    optimizer/checkpoints see. v moves into tile order (fwd) and dv back
+    (bwd) inside the jit, by key sorts (:func:`_to_tiles`,
+    :func:`_to_flat`), so only the layout-independent flat v is ever
     state; tile order is a pure function of the int consts from
     ``prepare_tile_consts``. Its device ops carry the pass they belong to
     in their scope: ``fwd``, and in the backward ``dx`` and ``dv``."""
     with jax.named_scope("fwd"):
-        return sl_matmul(x, B, A, _gather_tiles(v, perm), rows_t, cols_t,
-                         scale)
+        return sl_matmul(x, B, A, _to_tiles(v, perm), rows_t, cols_t, scale)
 
 
 def _sl_linear_fwd(x, B, A, v, rows_t, cols_t, perm, scale):
     with jax.named_scope("fwd"):
-        v_t = _gather_tiles(v, perm)
+        v_t = _to_tiles(v, perm)
         y = sl_matmul(x, B, A, v_t, rows_t, cols_t, scale)
     # residuals stay factored-sized (Alg. 1): v_t is nnz+pad floats, never
     # the (d_in, d_out) dense W
@@ -361,15 +421,9 @@ def _sl_linear_fwd(x, B, A, v, rows_t, cols_t, perm, scale):
 def _sl_linear_bwd(scale, res, dy):
     x, B, A, v, v_t, rows_t, cols_t, perm = res
     dx, dB, dA, dv_t = _fused_grads(x, B, A, v_t, rows_t, cols_t, scale, dy)
-    # scatter the f32 tile grads back through perm onto the flat layout;
-    # every valid perm entry appears exactly once (tile_layout invariant)
-    # so the add is exact, padding rides the clipped index with a 0 value
     with jax.named_scope("dv"):
-        pf = perm.reshape(-1)
-        flat = jnp.where(pf >= 0, dv_t.reshape(-1), 0.0)
-        dv = jnp.zeros((v.size,), jnp.float32).at[
-            jnp.clip(pf, 0, v.size - 1)].add(flat)
-    return (dx, dB, dA, dv.reshape(v.shape).astype(v.dtype),
+        dv = _to_flat(dv_t, perm, v)
+    return (dx, dB, dA, dv.astype(v.dtype),
             None, None, None)
 
 

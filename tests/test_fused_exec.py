@@ -140,6 +140,150 @@ def test_fused_dv_bf16_matches_dense_take_along_axis_grad():
 
 
 # ---------------------------------------------------------------------------
+# v between its flat layout and tile order: key sorts, exact
+# ---------------------------------------------------------------------------
+
+# whole row tiles, and d_in 300: a last row tile of 44 rows
+PERMUTE_CASES = [
+    pytest.param(kind, layers, d_in, d_out,
+                 id=f"{kind}-{layers}layer-{d_in}x{d_out}")
+    for kind, layers, d_in, d_out in (
+        ("row_balanced", 1, 256, 384), ("row_balanced", 2, 256, 384),
+        ("iid", 1, 256, 384), ("iid", 2, 256, 384),
+        ("row_balanced", 2, 300, 200), ("iid", 1, 300, 200))]
+
+
+def _stacked_layers(kind, layers, d_in, d_out, r=16):
+    """``layers`` fused SL linears of one shape, stacked on a leading axis
+    as the model's scan stacks them. Every layer has padding slots."""
+    ps, cs = zip(*[sltrain.init_params(
+        jax.random.PRNGKey(20 + i), d_in, d_out, r, 0.03, jnp.bfloat16,
+        kind, seed=30 + i, exec_mode="fused") for i in range(layers)])
+    params = jax.tree.map(lambda *a: jnp.stack(a), *ps)
+    consts = jax.tree.map(lambda *a: jnp.stack(a), *cs)
+    assert bool((consts["perm"] < 0).any(axis=(1, 2, 3)).all())
+    return params, consts
+
+
+def _per_layer(fn, *stacked):
+    """``fn`` over the stacked layers inside a jit'd ``lax.scan``."""
+    def run(*xs):
+        return jax.lax.scan(lambda c, sl: (c, fn(*sl)), None, xs)[1]
+    return jax.jit(run)(*stacked)
+
+
+def _gather_oracle(v, perm):
+    """The layout's definition: slot s holds v[perm[s]], pads hold 0."""
+    vf = np.asarray(v, np.float32).reshape(-1)
+    p = np.asarray(perm)
+    return np.where(p >= 0, vf[np.maximum(p, 0)], 0.0)
+
+
+def _scatter_add_oracle(dv_t, perm, nnz):
+    """dv by a scatter-add of the valid slots onto zeros."""
+    p = np.asarray(perm).reshape(-1)
+    d = np.asarray(dv_t, np.float32).reshape(-1)
+    out = np.zeros(nnz, np.float32)
+    np.add.at(out, p[p >= 0], d[p >= 0])
+    return out
+
+
+@pytest.mark.parametrize("kind,layers,d_in,d_out", PERMUTE_CASES)
+def test_sort_to_tiles_equals_the_gather(kind, layers, d_in, d_out):
+    params, consts = _stacked_layers(kind, layers, d_in, d_out)
+    v_t = _per_layer(ops._to_tiles, params["v"], consts["perm"])
+    assert v_t.dtype == jnp.float32
+    for i in range(layers):
+        # exact, and array_equal holds -0.0 == 0.0 on the pads
+        assert np.array_equal(
+            np.asarray(v_t[i]),
+            _gather_oracle(params["v"][i], consts["perm"][i]))
+
+
+@pytest.mark.parametrize("kind,layers,d_in,d_out", PERMUTE_CASES)
+def test_sort_to_flat_equals_a_scatter_add(kind, layers, d_in, d_out):
+    params, consts = _stacked_layers(kind, layers, d_in, d_out)
+    perm = consts["perm"]
+    # nonzero grads on the pad slots too, as sddmm leaves them
+    dv_t = jax.random.normal(jax.random.PRNGKey(9), perm.shape, jnp.float32)
+    nnz = params["v"][0].size
+    dv = _per_layer(lambda d, p, v: ops._to_flat(d, p, v).reshape(-1),
+                    dv_t, perm, params["v"])
+    for i in range(layers):
+        np.testing.assert_array_equal(
+            np.asarray(dv[i]), _scatter_add_oracle(dv_t[i], perm[i], nnz))
+
+
+@pytest.mark.parametrize("kind,layers,d_in,d_out", PERMUTE_CASES)
+def test_sl_linear_grad_matches_dense_reference(kind, layers, d_in, d_out):
+    """jax.grad through the fused linear, layers in a scan, against the
+    dense path (the take_along_axis gather for row-balanced v, the COO
+    densify for iid), with the bound of the single-layer bf16 test."""
+    params, consts = _stacked_layers(kind, layers, d_in, d_out)
+    params["B"] = (jax.random.normal(jax.random.PRNGKey(4),
+                                     params["B"].shape) * 0.1
+                   ).astype(jnp.bfloat16)
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((96, d_in)), jnp.bfloat16)
+    dy = jnp.asarray(rng.standard_normal((layers, 96, d_out)), jnp.float32)
+
+    def loss(p, mode):
+        def layer(c, xs):
+            pl, cl, dyl = xs
+            y = sltrain.sl_matmul(x, pl, cl, 0.5, mode).astype(jnp.float32)
+            return c + jnp.sum(y * dyl), None
+        return jax.lax.scan(layer, 0.0, (p, consts, dy))[0]
+
+    gd = jax.jit(jax.grad(lambda p: loss(p, "dense")))(params)
+    gf = jax.jit(jax.grad(lambda p: loss(p, "fused")))(params)
+    for name in ("v", "B", "A"):
+        d = np.asarray(gd[name], np.float32)
+        f = np.asarray(gf[name], np.float32)
+        np.testing.assert_allclose(f, d, rtol=1e-2,
+                                   atol=1e-2 * np.abs(d).max())
+
+
+def test_fused_step_records_one_sort_permute_per_pass_per_linear():
+    """Tracing one fused training step records ``sl.permute`` instants for
+    the forward and for dv of every SL linear, each a sort, one per trace
+    of the pass: dv once, the scanned layer body's forward as often as
+    JAX traces it (the same count for every linear)."""
+    import collections
+
+    import repro.obs
+    cfg = _fused_smoke_cfg()
+    api = registry.get_api(cfg)
+    params, consts = api.init(cfg, jax.random.PRNGKey(0), seed=0)
+    opt = optimizers.make(OptimizerConfig(lr=1e-3))
+    batch = {k: jnp.asarray(v) for k, v in
+             SyntheticC4(cfg.vocab_size, 32, 2, seed=0).next_batch().items()}
+
+    def permutes():
+        return [e["args"] for e in repro.obs.get_trace().events
+                if e["name"] == "sl.permute"]
+
+    n0 = len(permutes())
+    jax.jit(step_lib.make_train_step(cfg, api, opt)).lower(
+        params, opt.init(params), consts, batch)
+    got = permutes()[n0:]
+    # slots of one layer of each SL linear in the layer stack
+    slots = collections.Counter(
+        leaf[0].size for p, leaf in jax.tree_util.tree_flatten_with_path(
+            consts["layers"])[0] if str(getattr(p[-1], "key", "")) == "perm")
+    n_linears = sum(slots.values())
+    assert n_linears == 7
+    assert all(a["how"] == "sort" and a["slots"] >= a["nnz"] > 0
+               for a in got)
+    for pass_, traces in (("fwd", None), ("dv", 1)):
+        seen = collections.Counter(a["slots"] for a in got
+                                   if a["pass"] == pass_)
+        n = traces or sum(seen.values()) // n_linears
+        assert n >= 1
+        assert seen == collections.Counter(
+            {s: c * n for s, c in slots.items()}), (pass_, seen)
+
+
+# ---------------------------------------------------------------------------
 # Satellite: deterministic tile capacity + host re-sample fallback
 # ---------------------------------------------------------------------------
 

@@ -1,8 +1,9 @@
 """The per-layer readers of the entry, loop and kernels layers
 (``benchmarks/chip/metrics/*.py``) on a synthesized span list: compiles
 before, inside and after the window, tile tables before it, the host
-gap between one step's sync and the next step's dispatch, and the SL
-kernels' row blocks per call."""
+gap between one step's sync and the next step's dispatch, the SL
+kernels' row blocks per call, and the share of the SL linears' layout
+permutations that run as sorts."""
 import importlib.util
 import os
 
@@ -14,7 +15,7 @@ METRICS = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
                        "chip", "metrics")
 READERS = ("setup_compile_s.train", "setup_tile_tables_s.train",
            "window_compiles.train", "trainer_host_gap_ms.train",
-           "sl_row_blocks.train")
+           "sl_row_blocks.train", "sl_permute_sort_share.train")
 
 
 def reader(name):
@@ -48,6 +49,13 @@ def row_blocks(ts, kernel, m, rows, n_blocks, tiles=40 * 216):
     return {"name": "sl.row_blocks", "ph": "i", "ts": float(ts),
             "args": {"kernel": kernel, "m": m, "rows": rows,
                      "row_blocks": n_blocks, "tiles": tiles}}
+
+
+def permute(ts, pass_, how, slots=40 * 216 * 688, nnz=5120 * 829):
+    """The ``sl.permute`` instant ``kernels/ops.sl_linear`` records per
+    traced pass."""
+    return {"name": "sl.permute", "ph": "i", "ts": float(ts),
+            "args": {"pass": pass_, "how": how, "slots": slots, "nnz": nnz}}
 
 
 def steps(first_id, t0, n, period=1000.0):
@@ -147,6 +155,24 @@ def test_sl_row_blocks_reads_nothing_without_the_instants(events):
     assert reader("sl_row_blocks.train").read(CTX) is None
 
 
+def test_sl_permute_sort_share_reads_the_share_of_sorts(events):
+    # a sort forward twice, a gather forward once, a sort dv once; an
+    # instant after the window (the harness's later compile) is not read
+    events += [permute(1_100_000, "fwd", "sort"),
+               permute(1_100_010, "fwd", "sort"),
+               permute(1_100_020, "fwd", "gather"),
+               permute(1_100_030, "dv", "sort"),
+               permute(9_000_000, "dv", "scatter")]
+    assert reader("sl_permute_sort_share.train").read(CTX) == \
+        pytest.approx(75.0)
+
+
+def test_sl_permute_sort_share_reads_nothing_without_the_instants(events):
+    # the window's spans are there; the parent's linear records no instant
+    events.append(row_blocks(1_100_000, "sl_matmul", 2048, 2048, 1))
+    assert reader("sl_permute_sort_share.train").read(CTX) is None
+
+
 @pytest.mark.parametrize("name", READERS)
 def test_readers_read_nothing_without_the_programs_spans(name, monkeypatch):
     monkeypatch.setattr(repro.obs, "get_trace", lambda: _Recorder([]))
@@ -179,4 +205,5 @@ def test_traced_tiny_cell_reports_the_new_metrics(tmp_path):
     assert got["setup_tile_tables_s.train"] > 0
     assert got["trainer_host_gap_ms.train"] > 0
     assert got["sl_row_blocks.train"] == 1.0
+    assert got["sl_permute_sort_share.train"] == 100.0
     assert res["correct"]
